@@ -34,6 +34,9 @@ def main() -> None:
                          "the BENCH_*.json writes")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     failures: list[str] = []
 
     def section(name: str, fn) -> None:

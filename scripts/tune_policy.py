@@ -22,6 +22,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.mpc import MPCConfig
 from repro.core.simulator import SimCase, simulate_many
 from repro.experiment import Scenario
@@ -88,6 +89,7 @@ def tune(policy="carbonflex-mpc", grid=None, region="south-australia",
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     scale = "--scale" in sys.argv
     policy = "carbonflex-scale" if scale else "carbonflex-mpc"

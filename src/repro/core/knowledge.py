@@ -143,7 +143,9 @@ def _knn_jax(cases: jnp.ndarray, query: jnp.ndarray, k: int):
 def _knn_jax_batch(cases: jnp.ndarray, queries: jnp.ndarray, k: int):
     qn = jnp.sum(queries * queries, axis=1, keepdims=True)
     xn = jnp.sum(cases * cases, axis=1)[None, :]
-    d2 = qn + xn - 2.0 * queries @ cases.T
+    # HIGHEST: a TPU's default f32 matmul is one bf16 pass (see kernels/knn)
+    d2 = qn + xn - 2.0 * jnp.dot(queries, cases.T,
+                                 precision=jax.lax.Precision.HIGHEST)
     neg, idx = jax.lax.top_k(-d2, k)
     return jnp.sqrt(jnp.maximum(-neg, 0.0)), idx
 

@@ -42,7 +42,9 @@ The single remaining device-side float *combination* is the geo
 migration economics ``mean*e_run + mig_carbon`` (one add), where FMA
 contraction can differ from numpy in the last ulp; a decision flips only
 on an exact tie between move and stay — measure-zero on real traces and
-pinned empirically by the randomized parity suite.
+pinned empirically by the randomized parity suite.  A TPU has no native
+float64 and emulates it; ``chip_smoke.py`` holds every scan-native
+family, geo included, bit-equal to the scalar engine on one.
 
 Native coverage and delegation
 ------------------------------
@@ -81,7 +83,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from . import emissions
 from .baselines import (CarbonAgnosticPolicy, RobustWaitAwhilePolicy,
@@ -141,7 +142,7 @@ def native_kind(policy, cluster, faults) -> str | None:
 
 def _pad_rows(n: int) -> int:
     """Smallest ROW_PAD multiple strictly greater than n (the last row is
-    always padding — the gating kernel self-loops its edge padding there)."""
+    always padding — the dependency gating points its edge padding there)."""
     return (n // ROW_PAD + 1) * ROW_PAD
 
 
@@ -1434,7 +1435,7 @@ def simulate_scan(jobs, ci, cluster, policy, t0: int = 0,
     horizon = int(horizon if horizon is not None else len(ci) - t0)
     ci_pol = _policy_ci_view(ci)
     policy.on_window_start(ci_pol, t0, horizon, packed.jobs, cluster)
-    with enable_x64():
+    with jax.enable_x64(True):
         if kind in _SINGLE_KINDS:
             return _run_single_native(packed, ci, ci_pol, cluster, policy,
                                       t0, horizon, max_overrun, kind,
@@ -1455,7 +1456,7 @@ def simulate_many_scan(cases: Sequence) -> list[SimResult]:
     results: list[SimResult | None] = [None] * len(cases)
     groups: dict[tuple, list[tuple[int, object, object, _SingleProgram]]] = {}
     delegated: dict[str, int] = {}
-    with enable_x64():
+    with jax.enable_x64(True):
         for i, case in enumerate(cases):
             packed = _packed_for(case.jobs)
             telemetry = getattr(case, "telemetry", None)

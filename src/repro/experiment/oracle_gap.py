@@ -279,6 +279,9 @@ def main() -> None:
     ap.add_argument("--out", default=None, help="write result JSON here")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         base = Scenario(region=args.region, capacity=6, learn_weeks=1,
                         family="alibaba", seed=101)

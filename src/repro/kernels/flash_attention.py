@@ -8,8 +8,8 @@ head ``h // group`` — no KV replication in HBM.
 
 Grid: (batch, q_heads, nQ, nK) with ``dimension_semantics = (parallel,
 parallel, parallel, arbitrary)``; the output tile is written at the last
-KV step.  Validated in interpret mode against ``ref.flash_attention_ref``
-(this container is CPU-only; TPU is the target).
+KV step.  Validated against ``ref.flash_attention_ref``; compiled to
+Mosaic on a TPU and interpreted elsewhere (``knn._resolve_interpret``).
 """
 from __future__ import annotations
 
@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .knn import _resolve_interpret
 
 BLOCK_Q = 128
 BLOCK_K = 128
@@ -68,9 +70,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 @functools.partial(jax.jit, static_argnames=("causal_offset", "interpret",
                                              "block_q", "block_k"))
 def gqa_flash(q: jax.Array, k: jax.Array, v: jax.Array,
-              causal_offset: int = 0, interpret: bool = True,
+              causal_offset: int = 0, interpret: bool | None = None,
               block_q: int = BLOCK_Q, block_k: int = BLOCK_K) -> jax.Array:
     """q: (B, Sq, H, D); k/v: (B, Sk, KV, D) -> (B, Sq, H, D), causal."""
+    interpret = _resolve_interpret(interpret)
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
     group = hq // hkv

@@ -1,10 +1,10 @@
-"""Pallas kernel for DAG dependency gating: the per-slot gather/scatter
-that decrements child in-degree counters when parents finish.
+"""DAG dependency gating: the per-slot gather/scatter that decrements
+child in-degree counters when parents finish.
 
 The scan engine (``core/scan_engine.py``) carries a per-row ``pred_left``
 vector; each slot it needs ``dec[child] = sum over edges of
 fin[parent]`` — a gather over the edge parent list followed by a
-segment scatter-add over the edge child list.  Three implementations:
+segment scatter-add over the edge child list.  Two implementations:
 
 - :func:`dep_decrement` — pure ``jnp`` gather + ``.at[].add`` scatter.
   On XLA:CPU the scatter lowers to a serial per-element loop, so the
@@ -14,30 +14,15 @@ segment scatter-add over the edge child list.  Three implementations:
   dense padded predecessor-list gather + row sum; the scan engine's
   default whenever the max in-degree is modest (~6x cheaper on CPU,
   exactly equal counts because integer addition commutes).
-- :func:`dep_decrement_pallas` — the same contraction as a Pallas
-  kernel.  The edge lists are tiled over the grid; every grid step maps
-  to the *same* output block (Pallas serialises revisited output blocks,
-  so the accumulation is race-free) and performs its tile's gather +
-  scatter in VMEM.  On TPU this keeps the whole decrement on-chip; off
-  TPU it runs in interpreter mode (this container is CPU-only), so it is
-  exercised for parity, not speed — ``default_interpret`` resolution
-  follows ``kernels/knn.py``.
 
-All three return identical int32 counts (asserted in
+Both return identical int32 counts (asserted in
 ``tests/test_scan_engine.py``); integer arithmetic, so equality is exact
 on every backend.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-from .knn import _resolve_interpret
-
-EDGE_BLOCK = 1024
 
 
 def dep_decrement(fin: jax.Array, parents: jax.Array, children: jax.Array,
@@ -63,53 +48,3 @@ def dep_decrement_gather(fin: jax.Array, pred_rows: jax.Array) -> jax.Array:
     cheaper).  The scan engine uses it whenever the workload's max
     in-degree is small enough for the dense transpose to pay off."""
     return jnp.sum(fin[pred_rows].astype(jnp.int32), axis=1)
-
-
-def _gating_kernel(fin_ref, parents_ref, children_ref, out_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    par = parents_ref[...]
-    chd = children_ref[...]
-    contrib = fin_ref[...][par].astype(jnp.int32)
-    out_ref[...] = out_ref[...].at[chd].add(contrib)
-
-
-@functools.partial(jax.jit, static_argnames=("n", "interpret"))
-def _dep_decrement_pallas(fin, parents, children, n: int, interpret: bool):
-    e = parents.shape[0]
-    ep = max(EDGE_BLOCK, ((e + EDGE_BLOCK - 1) // EDGE_BLOCK) * EDGE_BLOCK)
-    # pad edges with a self-loop on the last (padding) row: fin there is
-    # False by construction, so padded edges contribute 0
-    pad_row = n - 1
-    parents_p = jnp.full(ep, pad_row, parents.dtype).at[:e].set(parents)
-    children_p = jnp.full(ep, pad_row, children.dtype).at[:e].set(children)
-    return pl.pallas_call(
-        _gating_kernel,
-        grid=(ep // EDGE_BLOCK,),
-        in_specs=[
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((EDGE_BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((EDGE_BLOCK,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((n,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-        interpret=interpret,
-    )(fin, parents_p, children_p)
-
-
-def dep_decrement_pallas(fin: jax.Array, parents: jax.Array,
-                         children: jax.Array, n: int,
-                         interpret: bool | None = None) -> jax.Array:
-    """Pallas-kernel variant of :func:`dep_decrement` (see module doc).
-
-    The caller guarantees ``fin[n - 1]`` is a padding row that never
-    finishes (the scan engine's layout); edge padding self-loops there.
-    """
-    if parents.shape[0] == 0:
-        return jnp.zeros(n, dtype=jnp.int32)
-    return _dep_decrement_pallas(fin, parents, children, n,
-                                 _resolve_interpret(interpret))

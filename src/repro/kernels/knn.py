@@ -18,7 +18,7 @@ wrapper — top-k over a few thousand scalars is not worth a custom kernel.
 
 ``interpret`` resolution: ``None`` (the default) auto-detects the backend
 — the kernels compile to Mosaic on TPU and fall back to the Pallas
-interpreter everywhere else (this container is CPU-only).  Callers can
+interpreter on every other backend.  Callers can
 force either mode explicitly (``KnowledgeBase(pallas_interpret=...)``
 plumbs through to here).
 """
@@ -98,7 +98,10 @@ def _dist_kernel_batch(queries_ref, cases_ref, out_ref):
     qn = jnp.sum(q * q, axis=1, keepdims=True)      # (BLOCK_Q, 1)
     xn = jnp.sum(x * x, axis=1, keepdims=True)      # (BLOCK_N, 1)
     # MXU block: -2 q.x^T, then the rank-1 norm corrections on the VPU.
-    cross = jnp.dot(q, x.T, preferred_element_type=jnp.float32)
+    # HIGHEST: the TPU's default f32 matmul is one bf16 pass, whose error
+    # (~0.1 in d^2 on Table-2 features) reorders near neighbours.
+    cross = jnp.dot(q, x.T, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
     out_ref[...] = qn + xn.T - 2.0 * cross
 
 

@@ -1,13 +1,11 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels.
 
-``interpret=True`` everywhere by default: this container is CPU-only, so
-the kernels execute through the Pallas interpreter for correctness; on a
-real TPU deployment set ``REPRO_PALLAS_INTERPRET=0`` (or pass
-``interpret=False``) to compile to Mosaic.
+``interpret=None`` (the default) resolves through
+``knn._resolve_interpret``: the kernels compile to Mosaic on a TPU and run
+in the Pallas interpreter on any other backend.  Pass ``interpret`` to
+force either mode.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -15,30 +13,23 @@ from . import flash_attention as _fa
 from . import knn as _knn
 from . import score as _score
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
 
 def knn_topk(cases: jax.Array, query: jax.Array, k: int,
              interpret: bool | None = None):
-    return _knn.knn_topk(cases, query, k,
-                         interpret=_INTERPRET if interpret is None else interpret)
+    return _knn.knn_topk(cases, query, k, interpret=interpret)
 
 
 def knn_topk_batch(cases: jax.Array, queries: jax.Array, k: int,
                    interpret: bool | None = None):
-    return _knn.knn_topk_batch(
-        cases, queries, k,
-        interpret=_INTERPRET if interpret is None else interpret)
+    return _knn.knn_topk_batch(cases, queries, k, interpret=interpret)
 
 
 def score_matrix(marginals, ci, t_start, t_end, interpret: bool | None = None):
-    return _score.score_matrix(
-        marginals, ci, t_start, t_end,
-        interpret=_INTERPRET if interpret is None else interpret)
+    return _score.score_matrix(marginals, ci, t_start, t_end,
+                               interpret=interpret)
 
 
 def flash_attention(q, k, v, causal_offset: int = 0,
                     interpret: bool | None = None, **kw):
     return _fa.gqa_flash(q, k, v, causal_offset=causal_offset,
-                         interpret=_INTERPRET if interpret is None else interpret,
-                         **kw)
+                         interpret=interpret, **kw)

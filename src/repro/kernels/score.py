@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .knn import _resolve_interpret
+
 BLOCK_J = 256
 BLOCK_T = 128
 
@@ -34,7 +36,7 @@ def _score_kernel(marg_ref, ts_ref, te_ref, ci_ref, out_ref, *, block_t):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def score_matrix(marginals: jax.Array, ci: jax.Array, t_start: jax.Array,
-                 t_end: jax.Array, interpret: bool = True) -> jax.Array:
+                 t_end: jax.Array, interpret: bool | None = None) -> jax.Array:
     """(J,), (T,), (J,), (J,) -> (J, T) masked scores."""
     j, t = marginals.shape[0], ci.shape[0]
     jp = ((j + BLOCK_J - 1) // BLOCK_J) * BLOCK_J
@@ -54,6 +56,6 @@ def score_matrix(marginals: jax.Array, ci: jax.Array, t_start: jax.Array,
         ],
         out_specs=pl.BlockSpec((BLOCK_J, BLOCK_T), lambda ti, ji: (ji, ti)),
         out_shape=jax.ShapeDtypeStruct((jp, tp), jnp.float32),
-        interpret=interpret,
+        interpret=_resolve_interpret(interpret),
     )(marg, ts, te, civ)
     return out[:j, :t]

@@ -115,11 +115,7 @@ def analyze_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    # jax <= 0.4.x returns a one-dict list from cost_analysis(); newer
-    # versions return the dict itself.
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     stats = analyze_module(hlo, scan_trip_hints=hints)
     coll = stats.collectives
@@ -154,10 +150,10 @@ def analyze_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
         "lower_s": round(t_lower, 2),
         "compile_s": round(t_compile, 2),
         "memory": {
-            "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
-            "output_bytes": getattr(mem, "output_size_in_bytes", None),
-            "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
-            "peak_bytes": getattr(mem, "peak_memory_in_bytes", None),
+            "argument_bytes": mem.argument_size_in_bytes,
+            "output_bytes": mem.output_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes,
+            "peak_bytes": mem.peak_memory_in_bytes,
         },
         "cost": {k: float(v) for k, v in cost.items()
                  if isinstance(v, (int, float)) and "{" not in k},

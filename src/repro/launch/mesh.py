@@ -3,39 +3,18 @@
 ``make_production_mesh`` is a FUNCTION (not a module-level constant) so
 importing this module never touches jax device state.  The dry-run script
 sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any
-jax import to obtain the placeholder devices.
-
-Version compatibility: explicit mesh axis types (``jax.sharding.AxisType``
-plus the ``axis_types=`` kwarg on ``jax.make_mesh``/``AbstractMesh``)
-landed after jax 0.4.x.  On older versions a plain ``Mesh`` has exactly
-the ``Auto`` semantics we would request explicitly, so the helpers below
-feature-detect and fall back — callers never touch ``AxisType`` directly.
+jax import to obtain the placeholder devices.  Every mesh axis is
+``AxisType.Auto``.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-
-def _auto_axis_type():
-    """``jax.sharding.AxisType.Auto`` where it exists, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return getattr(axis_type, "Auto", None)
-
-
-def _make_mesh_kwargs(num_axes: int) -> dict:
-    auto = _auto_axis_type()
-    if auto is None:
-        return {}
-    if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-        return {}
-    return {"axis_types": (auto,) * num_axes}
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Arbitrary mesh (elastic scaling uses smaller DP extents)."""
-    return jax.make_mesh(shape, axes, **_make_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -45,15 +24,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Device-free ``AbstractMesh`` across the two constructor generations:
-    jax <= 0.4.x takes one ``((name, size), ...)`` tuple; newer versions
-    take ``(shape, axis_names)`` plus optional explicit axis types."""
-    ctor = jax.sharding.AbstractMesh
-    params = list(inspect.signature(ctor.__init__).parameters)
-    if len(params) > 1 and params[1] == "shape_tuple":
-        return ctor(tuple(zip(axes, shape)))
-    auto = _auto_axis_type()
-    kw = {}
-    if auto is not None and "axis_types" in params:
-        kw["axis_types"] = (auto,) * len(axes)
-    return ctor(shape, axes, **kw)
+    """Device-free ``AbstractMesh`` with the same axis types."""
+    return jax.sharding.AbstractMesh(shape, axes,
+                                     axis_types=(AxisType.Auto,) * len(axes))

@@ -104,3 +104,23 @@ class TestBatchedKernel:
 
         expected = jax.default_backend() != "tpu"
         assert knn.default_interpret() is expected
+
+
+@pytest.mark.parametrize("path", ["pallas", "jax"])
+def test_batch_cross_term_runs_at_full_f32_precision(path):
+    """A TPU's default f32 matmul is one bf16 pass (~0.1 error in d^2 on
+    normalised Table-2 features); both device paths of the batched
+    distance must ask for HIGHEST, which the CPU cannot show numerically."""
+    import jax
+
+    from repro.core.knowledge import _knn_jax_batch
+
+    cases = jnp.ones((300, 23), jnp.float32)
+    queries = jnp.ones((10, 23), jnp.float32)
+    if path == "pallas":
+        fn = lambda c, q: knn._squared_distances_batch(c, q, interpret=True)  # noqa: E731
+    else:
+        fn = lambda c, q: _knn_jax_batch(c, q, k=3)  # noqa: E731
+    jaxpr = str(jax.make_jaxpr(fn)(cases, queries))
+    assert "dot_general" in jaxpr
+    assert "DEFAULT" not in jaxpr and "HIGHEST" in jaxpr

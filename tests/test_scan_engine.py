@@ -1,12 +1,12 @@
-"""Scan-engine internals (ISSUE-8): native-kind dispatch, the gating
-kernel's three implementations, delegation boundaries, and the vmapped
+"""Scan-engine internals (ISSUE-8): native-kind dispatch, the dependency
+gating's two forms, delegation boundaries, and the vmapped
 batch tile — every path asserted bit-identical to the scalar reference.
 
 Cross-engine *end-to-end* parity per policy family lives in
 ``test_engine_parity.py`` / ``test_geo.py`` / ``test_dag.py`` /
 ``test_resilience.py``; this file pins the scan engine's own moving
-parts: which cases run natively vs delegate, that the gather-form and
-Pallas-form dependency decrements equal the scatter form exactly, and
+parts: which cases run natively vs delegate, that the gather-form
+dependency decrement equals the scatter form exactly, and
 that ``simulate_many`` fusing structurally identical scan cases into one
 vmapped program returns the same bytes as running them one at a time.
 """
@@ -67,16 +67,15 @@ def test_native_kind_dispatch():
     assert native_kind(Tweaked(), cluster, None) is None
 
 
-# --- gating kernel: scatter == gather == pallas -------------------------------
+# --- dependency gating: scatter == gather ------------------------------------
 
 
 @pytest.mark.parametrize("seed", [0, 3, 11])
 @pytest.mark.parametrize("n_edges", [0, 17, 2048])
-def test_dep_decrement_three_way_parity(seed, n_edges):
-    """The scatter-form jnp decrement, the transposed gather form the
-    scan engine prefers on CPU, and the Pallas kernel must return the
-    same int32 counts on random edge sets (integer addition: exact in
-    any order)."""
+def test_dep_decrement_scatter_gather_parity(seed, n_edges):
+    """The scatter-form jnp decrement and the transposed gather form the
+    scan engine prefers on CPU must return the same int32 counts on
+    random edge sets (integer addition: exact in any order)."""
     rng = np.random.default_rng(seed)
     n = 256  # row n-1 is padding and never finishes
     fin = np.zeros(n, dtype=bool)
@@ -96,11 +95,7 @@ def test_dep_decrement_three_way_parity(seed, n_edges):
     scatter = gating.dep_decrement(fin_j, jnp.asarray(parents),
                                    jnp.asarray(children), n)
     gather = gating.dep_decrement_gather(fin_j, jnp.asarray(pred_rows))
-    pallas = gating.dep_decrement_pallas(fin_j, jnp.asarray(parents),
-                                         jnp.asarray(children), n,
-                                         interpret=True)
     np.testing.assert_array_equal(np.asarray(scatter), np.asarray(gather))
-    np.testing.assert_array_equal(np.asarray(scatter), np.asarray(pallas))
 
 
 # --- scan-native parity off the fast paths ------------------------------------
