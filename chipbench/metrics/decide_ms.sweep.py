@@ -1,0 +1,6 @@
+"""decide_ms.sweep: milliseconds of the program's ``decide`` phase per cell."""
+from chipbench.readers import phase_ms
+
+
+def read(ctx):
+    return phase_ms(ctx, "decide", "sweep")

@@ -1,0 +1,6 @@
+"""learn_ms.run: milliseconds of the program's ``learn`` phase per policy-week."""
+from chipbench.readers import phase_ms
+
+
+def read(ctx):
+    return phase_ms(ctx, "learn", "run")

@@ -1,0 +1,6 @@
+"""provision_ms.run: milliseconds of the program's ``provision`` phase per policy-week."""
+from chipbench.readers import phase_ms
+
+
+def read(ctx):
+    return phase_ms(ctx, "provision", "run")
