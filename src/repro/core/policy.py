@@ -30,6 +30,7 @@ from .knowledge import KnowledgeBase, build_state, states_from_schedule
 from .provisioning import ProvisioningConfig, provision
 from .scheduling import ActiveJob, schedule, schedule_packed
 from .types import ClusterConfig, Job
+from ..telemetry import PhaseProfiler, span
 
 _EPS = 1e-9
 
@@ -90,6 +91,7 @@ def learn_window(
     num_queues: int | None = None,
     offsets: tuple[int, ...] = (0,),
     backend: str = "numpy",
+    profiler: PhaseProfiler | None = None,
 ) -> LearnOutcome:
     """Learning phase over one historical window (optionally replayed at
     several start offsets, §5 'Continuous Learning').
@@ -97,6 +99,7 @@ def learn_window(
     ``cluster`` is a ``ClusterConfig``; the loose ``(capacity, num_queues)``
     integer pair is still accepted but deprecated.  Offsets whose window
     contains no arrivals are skipped and reported in ``LearnOutcome.empty``.
+    ``profiler`` times each oracle solve as the span ``learn/oracle``.
     """
     if isinstance(cluster, ClusterConfig):
         if num_queues is not None:
@@ -129,7 +132,9 @@ def learn_window(
             empty.append(off)
             continue
         ci_slice = ci.trace[s0:s0 + horizon]
-        res = oracle.solve(window_jobs, ci_slice, capacity, horizon=horizon, backend=backend)
+        with span(profiler, "learn/oracle"):
+            res = oracle.solve(window_jobs, ci_slice, capacity,
+                               horizon=horizon, backend=backend)
         states = states_from_schedule(window_jobs, res.schedule.alloc,
                                       ci, nq, t0=s0)
         kb.add_window(states, res.capacity_curve, res.rho_curve)

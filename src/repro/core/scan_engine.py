@@ -93,7 +93,7 @@ from .forecast import PerfectForecast, QuantileCIView
 from .geo import GeoFlexPolicy, GeoGreedyPolicy, GeoStaticPolicy
 from .mpc import CarbonFlexMPCPolicy, CarbonFlexScalePolicy
 from .types import GeoCluster, SimResult, SlotLog
-from ..telemetry import Telemetry
+from ..telemetry import PhaseProfiler, Telemetry, span
 
 _EPS = 1e-9
 _log = logging.getLogger(__name__)
@@ -282,7 +282,8 @@ def _single_elig_fn(policy, ci_pol, kind: str) -> Callable:
 
 
 def _build_single(packed, cluster, policy, ci_pol, kind: str,
-                  t0: int, horizon: int) -> _SingleProgram:
+                  t0: int, horizon: int,
+                  prof: PhaseProfiler | None = None) -> _SingleProgram:
     n = packed.n
     n_pad = _pad_rows(n)
     power = np.where(packed.power > 0, packed.power, cluster.power_per_server)
@@ -380,6 +381,8 @@ def _build_single(packed, cluster, policy, ci_pol, kind: str,
         pending=np.zeros(n_pad, dtype=bool),
         ended=np.asarray(False),
     ))
+    if prof is not None:
+        prof.count("h2d_bytes", _nbytes((consts, carry0)))
     if kind in _MPC_KINDS:
         # per-slot tables of the MPC rule, straight from the policy's own
         # host-precomputed arrays (bit-parity by construction)
@@ -566,7 +569,8 @@ class _GeoProgram:
 
 
 def _build_geo(packed, geo: GeoCluster, policy, ci_pol,
-               t0: int, horizon: int, kind: str) -> _GeoProgram:
+               t0: int, horizon: int, kind: str,
+               prof: PhaseProfiler | None = None) -> _GeoProgram:
     n = packed.n
     n_pad = _pad_rows(n)
     n_regions = geo.n_regions
@@ -628,25 +632,27 @@ def _build_geo(packed, geo: GeoCluster, policy, ci_pol,
         moves=np.zeros(n_pad, dtype=i64),
         ended=np.asarray(False),
     ))
+    if prof is not None:
+        prof.count("h2d_bytes", _nbytes((consts, carry0)))
 
-    # Per-chunk decision tables, one device_put each.  The CI/forecast
-    # blocks go through the batched whole-trace fast paths above (the
-    # per-slot Python API calls cost more than the device program);
-    # batched slice means are bitwise equal to the per-slot
-    # `fc[:, :h].mean(axis=1)` the policy computes (same pairwise
-    # reduction over the same values — ascontiguousarray only changes
-    # strides, never the reduction order).
+    # Per-chunk host decision tables (``_collect_chunks`` uploads each
+    # chunk's tables in one device_put).  The CI/forecast blocks go
+    # through the batched whole-trace fast paths above (the per-slot
+    # Python API calls cost more than the device program); batched slice
+    # means are bitwise equal to the per-slot `fc[:, :h].mean(axis=1)`
+    # the policy computes (same pairwise reduction over the same values —
+    # ascontiguousarray only changes strides, never the reduction order).
     def xs_fn(ts: np.ndarray) -> dict:
         s = len(ts)
         xs = {"t": ts.astype(i64)}
         if kind == "geo-static":
-            return jax.device_put(xs)
+            return xs
         civ = _ci_vec_block(ci_pol, ts)                           # (S, R)
         xs["ci_now"] = civ
         if kind == "geo-greedy":
             xs["clean_order"] = np.argsort(civ, axis=1,
                                            kind="stable").astype(i64)
-            return jax.device_put(xs)
+            return xs
         fc = np.ascontiguousarray(
             _forecast_block(ci_pol, ts, lookahead))               # (S, R, H)
         xs["thresh_eps"] = np.percentile(fc, percentile, axis=2) + _EPS
@@ -659,7 +665,7 @@ def _build_geo(packed, geo: GeoCluster, policy, ci_pol,
             for h in range(1, lookahead - ms + 1):
                 movem[:, mi, :, h - 1] = fc[:, :, ms:ms + h].mean(axis=2)
         xs["movemeans"] = movem
-        return jax.device_put(xs)
+        return xs
 
     return _GeoProgram(consts=consts, carry0=carry0, n_pad=n_pad, kind=kind,
                        uniform=bool((kmin == kmin[0]).all()), xs_fn=xs_fn,
@@ -1078,8 +1084,14 @@ def _active_energy_cells(packed, power, slot_h, eta, take_a, k_rows):
     return bounds, r_idx, k, e
 
 
-def _collect_chunks(prog_consts, carry, chunk_fn, xs_builder, t0: int,
-                    t_mid: int, t_hard: int) -> tuple[dict, int]:
+def _nbytes(tree) -> int:
+    """Bytes held by the arrays of a pytree (host or device)."""
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
+
+
+def _collect_chunks(prog_consts, carry, chunk_fn, xs_fn, t0: int,
+                    t_mid: int, t_hard: int,
+                    prof: PhaseProfiler | None = None) -> tuple[dict, int]:
     """Run device chunks until the case ends or t_hard; returns stacked
     host ys + the count of valid (pre-termination) slots.
 
@@ -1087,47 +1099,58 @@ def _collect_chunks(prog_consts, carry, chunk_fn, xs_builder, t0: int,
     engines' ended-check requires ``t >= t0 + horizon``), so full CHUNK
     dispatches are free of waste; past the horizon the case can end any
     slot, so smaller OVERRUN_CHUNK dispatches bound the slots computed
-    beyond the actual end."""
+    beyond the actual end.  With a profiler attached, each chunk's host
+    tables, upload, device wait and fetch are spans of ``decide``, and
+    the transfers and computed slots are counted."""
     ys_parts = []
     t_lo = t0
     while t_lo < t_hard:
         cap = CHUNK if t_lo < t_mid else OVERRUN_CHUNK
         size = min(cap, t_hard - t_lo)
-        ts = np.arange(t_lo, t_lo + size)
-        carry, ys = chunk_fn(prog_consts, carry, xs_builder(ts))
-        ys_parts.append(jax.device_get(ys))
+        with span(prof, "decide/tables"):
+            xs = xs_fn(np.arange(t_lo, t_lo + size))
+        with span(prof, "decide/upload"):
+            xs = jax.device_put(xs)
+        with span(prof, "decide/wait"):
+            carry, ys = chunk_fn(prog_consts, carry, xs)
+            if prof is not None:
+                jax.block_until_ready(ys)
+        with span(prof, "decide/fetch"):
+            ys = jax.device_get(ys)
+            ended = bool(np.asarray(carry["ended"]))
+        if prof is not None:
+            prof.count("h2d_bytes", _nbytes(xs))
+            prof.count("d2h_bytes", _nbytes(ys) + carry["ended"].nbytes)
+        ys_parts.append(ys)
         t_lo += size
-        if bool(np.asarray(carry["ended"])):
+        if ended:
             break
     ys = {k: np.concatenate([p[k] for p in ys_parts]) for k in ys_parts[0]}
     ended = np.asarray(ys["ended"], dtype=bool)
     n_valid = int(np.argmax(ended)) if ended.any() else len(ended)
+    if prof is not None:
+        prof.count("scan_slots", len(ended))
+        prof.count("scan_slots_past_end", len(ended) - n_valid)
     return ys, n_valid
 
 
 def _run_single_native(packed, ci, ci_pol, cluster, policy, t0, horizon,
                        max_overrun, kind,
                        telemetry: Telemetry | None = None) -> SimResult:
-    from .simulator import _run_resilience
-
-    prog = _build_single(packed, cluster, policy, ci_pol, kind, t0, horizon)
+    prof = telemetry.profiler if telemetry is not None else None
+    with span(prof, "build"):
+        prog = _build_single(packed, cluster, policy, ci_pol, kind, t0,
+                             horizon, prof)
     t_hard = t0 + horizon + max_overrun
-
-    def xs_builder(ts):
-        return jax.device_put(prog.xs_fn(ts))
 
     def chunk_fn(consts, carry, xs):
         return _single_chunk(consts, carry, xs, prog.kind, prog.uniform,
                              prog.deps)
 
-    prof = telemetry.profiler if telemetry is not None else None
-    if prof is not None:
-        _pt = time.perf_counter()
-    ys, n_valid = _collect_chunks(prog.consts, prog.carry0, chunk_fn,
-                                  xs_builder, t0, t0 + horizon, t_hard)
-    if prof is not None:
-        # device_get inside _collect_chunks already synchronised the scan
-        prof.add("decide", time.perf_counter() - _pt)
+    with span(prof, "decide"):
+        ys, n_valid = _collect_chunks(prog.consts, prog.carry0, chunk_fn,
+                                      prog.xs_fn, t0, t0 + horizon, t_hard,
+                                      prof)
     return _account_single(packed, ci, ci_pol, cluster, policy, t0, ys,
                            n_valid, prog, telemetry=telemetry)
 
@@ -1230,42 +1253,39 @@ def _account_single(packed, ci, ci_pol, cluster, policy, t0, ys, n_valid,
         kv = [float(k) for k in packed.k_min.tolist()]
         rr, rb, sr, sb = _scan_slot_events(take_a, fs, fr, n_valid)
         emit = tele.emit
-    if prof is not None:
-        _pt = time.perf_counter()
-    for i in range(n_valid):
-        t = t0 + i
-        civ = float(civ_a[i])
-        lo, hi = bounds[i], bounds[i + 1]
-        if tele is not None:
-            for r in admits_by.get(t, ()):
-                emit(t, "admit", job=jids[r])
-            if ci_pol is not ci:
-                emit(t, "forecast-read", value=float(ci_pol.staleness(t)))
-            for r in rr[rb[i]:rb[i + 1]]:
-                emit(t, "resume", job=jids[r], value=kv[r])
-            for r in sr[sb[i]:sb[i + 1]]:
-                emit(t, "suspend", job=jids[r])
-        energy = 0.0
-        for v in e_act[lo:hi].tolist():        # sequential sum, scalar order
-            energy += v
-        carbon = emissions.slot_carbon_g(energy, civ)
-        total_energy += energy
-        total_carbon += carbon
-        flo, fhi = fbounds[i], fbounds[i + 1]
-        frows = fr[flo:fhi]
-        if len(frows):
-            completion[frows] = t
-            wait[frows] = wfin_f[flo:fhi]
-            violations[frows] = viol_f[flo:fhi]
-        used = int(k_act[lo:hi].sum())
-        running = int(hi - lo)
-        logs.append(SlotLog(slot=t, ci=civ, provisioned=prog.m_t, used=used,
-                            energy_kwh=energy, carbon_g=carbon,
-                            running=running,
-                            queued=int(n_rows_a[i]) - len(frows)
-                            - running))
-    if prof is not None:
-        prof.add("execute", time.perf_counter() - _pt)
+    with span(prof, "execute"):
+        for i in range(n_valid):
+            t = t0 + i
+            civ = float(civ_a[i])
+            lo, hi = bounds[i], bounds[i + 1]
+            if tele is not None:
+                for r in admits_by.get(t, ()):
+                    emit(t, "admit", job=jids[r])
+                if ci_pol is not ci:
+                    emit(t, "forecast-read", value=float(ci_pol.staleness(t)))
+                for r in rr[rb[i]:rb[i + 1]]:
+                    emit(t, "resume", job=jids[r], value=kv[r])
+                for r in sr[sb[i]:sb[i + 1]]:
+                    emit(t, "suspend", job=jids[r])
+            energy = 0.0
+            for v in e_act[lo:hi].tolist():    # sequential sum, scalar order
+                energy += v
+            carbon = emissions.slot_carbon_g(energy, civ)
+            total_energy += energy
+            total_carbon += carbon
+            flo, fhi = fbounds[i], fbounds[i + 1]
+            frows = fr[flo:fhi]
+            if len(frows):
+                completion[frows] = t
+                wait[frows] = wfin_f[flo:fhi]
+                violations[frows] = viol_f[flo:fhi]
+            used = int(k_act[lo:hi].sum())
+            running = int(hi - lo)
+            logs.append(SlotLog(slot=t, ci=civ, provisioned=prog.m_t,
+                                used=used, energy_kwh=energy, carbon_g=carbon,
+                                running=running,
+                                queued=int(n_rows_a[i]) - len(frows)
+                                - running))
     return SimResult(
         policy=policy.name, carbon_g=total_carbon, energy_kwh=total_energy,
         slots=logs, wait_slots=wait, violations=violations,
@@ -1279,20 +1299,20 @@ def _run_geo_native(packed, mci, ci_pol, geo, policy, t0, horizon,
     from .simulator import (_accumulate_regions, _run_resilience,
                             _telemetry_hooks)
 
+    tele, prof, _, _ = _telemetry_hooks(telemetry, None)
     lookahead = int(getattr(policy, "lookahead", 24))
     t_hard = t0 + horizon + max_overrun
-    prog = _build_geo(packed, geo, policy, ci_pol, t0, horizon, kind)
+    with span(prof, "build"):
+        prog = _build_geo(packed, geo, policy, ci_pol, t0, horizon, kind,
+                          prof)
 
     def chunk_fn(consts, carry, xs):
         return _geo_chunk(consts, carry, xs, kind, lookahead, prog.uniform)
 
-    tele, prof, _, _ = _telemetry_hooks(telemetry, None)
-    if prof is not None:
-        _pt = time.perf_counter()
-    ys, n_valid = _collect_chunks(prog.consts, prog.carry0, chunk_fn,
-                                  prog.xs_fn, t0, t0 + horizon, t_hard)
-    if prof is not None:
-        prof.add("decide", time.perf_counter() - _pt)
+    with span(prof, "decide"):
+        ys, n_valid = _collect_chunks(prog.consts, prog.carry0, chunk_fn,
+                                      prog.xs_fn, t0, t0 + horizon, t_hard,
+                                      prof)
 
     n = packed.n
     n_regions = geo.n_regions
@@ -1334,62 +1354,59 @@ def _run_geo_native(packed, mci, ci_pol, geo, policy, t0, horizon,
         kv = [float(k) for k in packed.k_min.tolist()]
         rr, rb, sr, sb = _scan_slot_events(take_a, fs, fr, n_valid)
         emit = tele.emit
-    if prof is not None:
-        _pt = time.perf_counter()
-    for i in range(n_valid):
-        t = t0 + i
-        ci_vec = civ_a[i]
-        lo, hi = bounds[i], bounds[i + 1]
-        mrows = mr_idx[mbounds[i]:mbounds[i + 1]]
-        if tele is not None:
-            for r in admits_by.get(t, ()):
-                emit(t, "admit", job=jids[r])
-            if ci_pol is not mci:
-                emit(t, "forecast-read", value=float(ci_pol.staleness(t)))
-            for row in mrows.tolist():             # decision order
-                src = (int(reg_a[i - 1, row]) if i > 0
-                       else geo.home_region(row))
-                emit(t, "migrate", job=jids[row],
-                     value=float(reg_a[i, row]), detail=f"from={src}")
-            for r in rr[rb[i]:rb[i + 1]]:
-                emit(t, "resume", job=jids[r], value=kv[r])
-            for r in sr[sb[i]:sb[i + 1]]:
-                emit(t, "suspend", job=jids[r])
-        e_vec = e_act[lo:hi]
-        a_regions = areg_act[lo:hi]
-        energy_r = np.zeros(n_regions)
-        for r in range(n_regions):
-            for v in e_vec[a_regions == r].tolist():
-                energy_r[r] += v
-        mc = 0.0
-        for row in mrows.tolist():             # row order == decision order
-            e = prog.mig_e[row]
-            dest = int(reg_a[i, row])
-            energy_r[dest] += e
-            mc += e * ci_vec[dest]
-        mig_carbon_total += mc
-        migrations += len(mrows)
-        energy, carbon = _accumulate_regions(energy_r, ci_vec,
-                                             region_energy, region_carbon)
-        total_energy += energy
-        total_carbon += carbon
-        flo, fhi = fbounds[i], fbounds[i + 1]
-        frows = fr[flo:fhi]
-        if len(frows):
-            completion[frows] = t
-            wait[frows] = wfin_f[flo:fhi]
-            violations[frows] = viol_f[flo:fhi]
-            final_region[frows] = reg_a[i, frows]
-        used = int(k_act[lo:hi].sum())
-        running = int(hi - lo)
-        logs.append(SlotLog(slot=t, ci=float(np.mean(ci_vec)),
-                            provisioned=provisioned, used=used,
-                            energy_kwh=energy, carbon_g=carbon,
-                            running=running,
-                            queued=int(n_rows_a[i]) - len(frows)
-                            - running))
-    if prof is not None:
-        prof.add("execute", time.perf_counter() - _pt)
+    with span(prof, "execute"):
+        for i in range(n_valid):
+            t = t0 + i
+            ci_vec = civ_a[i]
+            lo, hi = bounds[i], bounds[i + 1]
+            mrows = mr_idx[mbounds[i]:mbounds[i + 1]]
+            if tele is not None:
+                for r in admits_by.get(t, ()):
+                    emit(t, "admit", job=jids[r])
+                if ci_pol is not mci:
+                    emit(t, "forecast-read", value=float(ci_pol.staleness(t)))
+                for row in mrows.tolist():             # decision order
+                    src = (int(reg_a[i - 1, row]) if i > 0
+                           else geo.home_region(row))
+                    emit(t, "migrate", job=jids[row],
+                         value=float(reg_a[i, row]), detail=f"from={src}")
+                for r in rr[rb[i]:rb[i + 1]]:
+                    emit(t, "resume", job=jids[r], value=kv[r])
+                for r in sr[sb[i]:sb[i + 1]]:
+                    emit(t, "suspend", job=jids[r])
+            e_vec = e_act[lo:hi]
+            a_regions = areg_act[lo:hi]
+            energy_r = np.zeros(n_regions)
+            for r in range(n_regions):
+                for v in e_vec[a_regions == r].tolist():
+                    energy_r[r] += v
+            mc = 0.0
+            for row in mrows.tolist():         # row order == decision order
+                e = prog.mig_e[row]
+                dest = int(reg_a[i, row])
+                energy_r[dest] += e
+                mc += e * ci_vec[dest]
+            mig_carbon_total += mc
+            migrations += len(mrows)
+            energy, carbon = _accumulate_regions(energy_r, ci_vec,
+                                                 region_energy, region_carbon)
+            total_energy += energy
+            total_carbon += carbon
+            flo, fhi = fbounds[i], fbounds[i + 1]
+            frows = fr[flo:fhi]
+            if len(frows):
+                completion[frows] = t
+                wait[frows] = wfin_f[flo:fhi]
+                violations[frows] = viol_f[flo:fhi]
+                final_region[frows] = reg_a[i, frows]
+            used = int(k_act[lo:hi].sum())
+            running = int(hi - lo)
+            logs.append(SlotLog(slot=t, ci=float(np.mean(ci_vec)),
+                                provisioned=provisioned, used=used,
+                                energy_kwh=energy, carbon_g=carbon,
+                                running=running,
+                                queued=int(n_rows_a[i]) - len(frows)
+                                - running))
     return SimResult(
         policy=policy.name, carbon_g=total_carbon, energy_kwh=total_energy,
         slots=logs, wait_slots=wait, violations=violations,
@@ -1412,8 +1429,10 @@ def simulate_scan(jobs, ci, cluster, policy, t0: int = 0,
     from .simulator import (_packed_for, _policy_ci_view, _simulate_vector,
                             _simulate_geo_vector)
 
+    prof = telemetry.profiler if telemetry is not None else None
     if packed is None:
-        packed = _packed_for(jobs)
+        with span(prof, "pack"):
+            packed = _packed_for(jobs)
     kind = native_kind(policy, cluster, faults)
     if (kind == "mpc-scale" and telemetry is not None
             and telemetry.recorder is not None):
@@ -1434,7 +1453,8 @@ def simulate_scan(jobs, ci, cluster, policy, t0: int = 0,
                                 telemetry=telemetry)
     horizon = int(horizon if horizon is not None else len(ci) - t0)
     ci_pol = _policy_ci_view(ci)
-    policy.on_window_start(ci_pol, t0, horizon, packed.jobs, cluster)
+    with span(prof, "policy_tables"):
+        policy.on_window_start(ci_pol, t0, horizon, packed.jobs, cluster)
     with jax.enable_x64(True):
         if kind in _SINGLE_KINDS:
             return _run_single_native(packed, ci, ci_pol, cluster, policy,
@@ -1458,8 +1478,10 @@ def simulate_many_scan(cases: Sequence) -> list[SimResult]:
     delegated: dict[str, int] = {}
     with jax.enable_x64(True):
         for i, case in enumerate(cases):
-            packed = _packed_for(case.jobs)
             telemetry = getattr(case, "telemetry", None)
+            prof = telemetry.profiler if telemetry is not None else None
+            with span(prof, "pack"):
+                packed = _packed_for(case.jobs)
             kind = native_kind(case.policy, case.cluster, case.faults)
             if (kind == "mpc-scale" and telemetry is not None
                     and telemetry.recorder is not None):
@@ -1481,8 +1503,9 @@ def simulate_many_scan(cases: Sequence) -> list[SimResult]:
             horizon = int(case.horizon if case.horizon is not None
                           else len(case.ci) - case.t0)
             ci_pol = _policy_ci_view(case.ci)
-            case.policy.on_window_start(ci_pol, case.t0, horizon,
-                                        packed.jobs, case.cluster)
+            with span(prof, "policy_tables"):
+                case.policy.on_window_start(ci_pol, case.t0, horizon,
+                                            packed.jobs, case.cluster)
             if kind not in _SINGLE_KINDS:
                 results[i] = _run_geo_native(packed, case.ci, ci_pol,
                                              case.cluster, case.policy,
@@ -1490,8 +1513,9 @@ def simulate_many_scan(cases: Sequence) -> list[SimResult]:
                                              case.max_overrun, kind,
                                              telemetry=telemetry)
                 continue
-            prog = _build_single(packed, case.cluster, case.policy, ci_pol,
-                                 kind, case.t0, horizon)
+            with span(prof, "build"):
+                prog = _build_single(packed, case.cluster, case.policy,
+                                     ci_pol, kind, case.t0, horizon, prof)
             dep_dim = (prog.consts["pred_rows"].shape[1]
                        if prog.deps == "gather"
                        else prog.consts["parents"].shape[0]
@@ -1520,65 +1544,88 @@ def _run_single_tile(members, results) -> None:
                       else len(case.ci) - case.t0)
         t_hard = case.t0 + horizon + case.max_overrun
 
-        def xs_builder(ts):
-            return jax.device_put(prog.xs_fn(ts))
-
         def chunk_fn(consts, carry, xs):
             return _single_chunk(consts, carry, xs, prog.kind, prog.uniform,
                                  prog.deps)
 
         telemetry = getattr(case, "telemetry", None)
         prof = telemetry.profiler if telemetry is not None else None
-        if prof is not None:
-            _pt = time.perf_counter()
-        ys, n_valid = _collect_chunks(prog.consts, prog.carry0, chunk_fn,
-                                      xs_builder, case.t0,
-                                      case.t0 + horizon, t_hard)
-        if prof is not None:
-            prof.add("decide", time.perf_counter() - _pt)
+        with span(prof, "decide"):
+            ys, n_valid = _collect_chunks(prog.consts, prog.carry0, chunk_fn,
+                                          prog.xs_fn, case.t0,
+                                          case.t0 + horizon, t_hard, prof)
         results[i] = _account_single(packed, case.ci, ci_pol, case.cluster,
                                      case.policy, case.t0, ys, n_valid, prog,
                                      telemetry=telemetry)
         return
 
+    tels = [getattr(m[1], "telemetry", None) for m in members]
+    profs = [t.profiler if t is not None else None for t in tels]
+    # a sweep's cells share one profiler: the tile is then one bracket of
+    # it, with the spans of each chunk; otherwise no spans, and the
+    # members split the tile's decide time evenly
+    prof = profs[0] if all(p is profs[0] for p in profs) else None
+    watched = any(p is not None for p in profs)
     kind_b = members[0][3].kind
     uniform = members[0][3].uniform
     deps = members[0][3].deps
-    consts = {k: jnp.stack([m[3].consts[k] for m in members])
-              for k in members[0][3].consts}
-    carry = {k: jnp.stack([m[3].carry0[k] for m in members])
-             for k in members[0][3].carry0}
+    with span(prof, "build"):
+        consts = {k: jnp.stack([m[3].consts[k] for m in members])
+                  for k in members[0][3].consts}
+        carry = {k: jnp.stack([m[3].carry0[k] for m in members])
+                 for k in members[0][3].carry0}
     horizon_b = int(members[0][1].horizon
                     if members[0][1].horizon is not None
                     else len(members[0][1].ci) - members[0][1].t0)
-    span = members[0][1].max_overrun + horizon_b
+    t_span = members[0][1].max_overrun + horizon_b
     ys_parts = []
-    off = 0
+    off = h2d = d2h = 0
     _dev_t0 = time.perf_counter()
-    while off < span:
-        size = min(CHUNK if off < horizon_b else OVERRUN_CHUNK, span - off)
-        xs_host = [m[3].xs_fn(np.arange(m[1].t0 + off, m[1].t0 + off + size))
-                   for m in members]
-        xs = {k: jnp.asarray(np.stack([d[k] for d in xs_host]))
-              for k in xs_host[0]}
-        carry, ys = _single_chunk_batch(consts, carry, xs, kind_b, uniform,
-                                        deps)
-        ys_parts.append(jax.device_get(ys))
-        off += size
-        if bool(np.asarray(carry["ended"]).all()):
-            break
-    # the vmapped dispatch is shared; split its wall-clock evenly across
-    # the tile so per-case phase totals still sum to real time
-    _dev_dt = (time.perf_counter() - _dev_t0) / len(members)
+    with span(prof, "decide"):
+        while off < t_span:
+            size = min(CHUNK if off < horizon_b else OVERRUN_CHUNK,
+                       t_span - off)
+            with span(prof, "decide/tables"):
+                xs_host = [m[3].xs_fn(np.arange(m[1].t0 + off,
+                                                m[1].t0 + off + size))
+                           for m in members]
+                xs = {k: np.stack([d[k] for d in xs_host])
+                      for k in xs_host[0]}
+            with span(prof, "decide/upload"):
+                xs = {k: jnp.asarray(v) for k, v in xs.items()}
+            with span(prof, "decide/wait"):
+                carry, ys = _single_chunk_batch(consts, carry, xs, kind_b,
+                                                uniform, deps)
+                if prof is not None:
+                    jax.block_until_ready(ys)
+            with span(prof, "decide/fetch"):
+                ys = jax.device_get(ys)
+                ended = bool(np.asarray(carry["ended"]).all())
+            if watched:
+                h2d += _nbytes(xs)
+                d2h += _nbytes(ys) + carry["ended"].nbytes
+            ys_parts.append(ys)
+            off += size
+            if ended:
+                break
+    if prof is None:
+        _dev_dt = (time.perf_counter() - _dev_t0) / len(members)
+        for p in profs:
+            if p is not None:
+                p.add("decide", _dev_dt)
     ys_all = {k: np.concatenate([p[k] for p in ys_parts], axis=1)
               for k in ys_parts[0]}
     for j, (i, case, packed, prog, ci_pol) in enumerate(members):
-        telemetry = getattr(case, "telemetry", None)
-        if telemetry is not None and telemetry.profiler is not None:
-            telemetry.profiler.add("decide", _dev_dt)
         ys = {k: v[j] for k, v in ys_all.items()}
         ended = np.asarray(ys["ended"], dtype=bool)
         n_valid = int(np.argmax(ended)) if ended.any() else len(ended)
+        if profs[j] is not None:
+            # every member computed every slot the tile ran; the stacked
+            # transfers divide evenly (members share their shapes)
+            profs[j].count("scan_slots", off)
+            profs[j].count("scan_slots_past_end", off - n_valid)
+            profs[j].count("h2d_bytes", h2d // len(members))
+            profs[j].count("d2h_bytes", d2h // len(members))
         results[i] = _account_single(packed, case.ci, ci_pol, case.cluster,
                                      case.policy, case.t0, ys, n_valid, prog,
-                                     telemetry=telemetry)
+                                     telemetry=tels[j])

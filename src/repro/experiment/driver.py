@@ -22,7 +22,7 @@ from repro.core.policy import learn_window
 from repro.core.simulator import SimCase, simulate_many
 from repro.core.types import SimResult
 from repro.serving import ServeCase, simulate_serving_many
-from repro.telemetry import Telemetry
+from repro.telemetry import PhaseProfiler, Telemetry, span
 
 from .registry import (PolicyContext, check_scenario_policies, get_spec,
                        make_policy, needs_kb)
@@ -56,16 +56,19 @@ def prepare_context(
     kb_kwargs: dict | None = None,
     backend: str = "numpy",
     forecast_quantile: float = 0.7,
+    profiler: PhaseProfiler | None = None,
 ) -> PolicyContext:
     """Build the :class:`PolicyContext` for a materialized scenario,
     running the initial learning phase when any requested policy needs the
     knowledge base.  ``forecast_quantile`` is the band the ``*-robust``
-    policy variants threshold on."""
+    policy variants threshold on; ``profiler`` times the oracle solves
+    (``learn/oracle``)."""
     kb = None
     if needs_kb(policies):
         kb = KnowledgeBase(**(kb_kwargs or {}))
         learn_window(kb, mat.hist, mat.ci, 0, WEEK, mat.cluster,
-                     offsets=mat.scenario.learn_offsets(), backend=backend)
+                     offsets=mat.scenario.learn_offsets(), backend=backend,
+                     profiler=profiler)
     return PolicyContext(
         cluster=mat.cluster, ci=mat.ci, history=list(mat.hist),
         mean_length=mat.mean_length, utilization=mat.scenario.utilization,
@@ -220,7 +223,8 @@ def run(
     ``telemetry`` (README §Observability) attaches a decision-trace
     recorder and/or phase profiler: every engine dispatch records under a
     ``"{policy}/w{week}"`` run label, and the learning/provisioning work
-    here brackets the profiler's ``learn``/``provision`` phases.  The
+    here brackets the profiler's ``learn``/``provision`` phases (and the
+    ``policy_tables`` span of policy construction).  The
     default ``None`` leaves every engine on its untouched zero-overhead
     path.
     """
@@ -234,19 +238,15 @@ def run(
                             scenario.is_serving)
     t_start = time.perf_counter()
     prof = telemetry.profiler if telemetry is not None else None
-    if prof is not None:
-        with prof.phase("provision"):
-            mat = scenario.materialize()
-        with prof.phase("learn"):
-            ctx = prepare_context(mat, names, kb_kwargs=kb_kwargs,
-                                  backend=backend,
-                                  forecast_quantile=forecast_quantile)
-    else:
-        mat = scenario.materialize()
+    with span(prof, "provision"):
+        mat = scenario.materialize(profiler=prof)
+    with span(prof, "learn"):
         ctx = prepare_context(mat, names, kb_kwargs=kb_kwargs,
                               backend=backend,
-                              forecast_quantile=forecast_quantile)
-    instances = {n: make_policy(n, ctx) for n in names}
+                              forecast_quantile=forecast_quantile,
+                              profiler=prof)
+    with span(prof, "policy_tables"):
+        instances = {n: make_policy(n, ctx) for n in names}
     weekly: dict[str, list[SimResult]] = {n: [] for n in names}
 
     if scenario.is_serving:
@@ -283,15 +283,10 @@ def run(
             # continuous learning: replay the week just evaluated
             prev = [j for j in mat.jobs if t0 - WEEK <= j.arrival < t0]
             if ctx.kb is not None:
-                if prof is not None:
-                    with prof.phase("learn"):
-                        learn_window(ctx.kb, mat.jobs, mat.ci, 0, WEEK,
-                                     mat.cluster, offsets=(t0 - WEEK,),
-                                     backend=backend)
-                else:
+                with span(prof, "learn"):
                     learn_window(ctx.kb, mat.jobs, mat.ci, 0, WEEK,
                                  mat.cluster, offsets=(t0 - WEEK,),
-                                 backend=backend)
+                                 backend=backend, profiler=prof)
             for n in names:
                 if get_spec(n).needs_history and prev:
                     instances[n].warm_start(prev)

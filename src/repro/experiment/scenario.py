@@ -25,6 +25,7 @@ from repro.core.mpc import MPCConfig
 from repro.core.types import (ClusterConfig, GeoCluster, Job, MigrationModel,
                               QueueConfig, default_queues)
 from repro.serving import MaterializedServing, ServingConfig
+from repro.telemetry import PhaseProfiler, span
 from repro.traces import (DagConfig, TraceSpec, dag_mean_task_length,
                           expected_request_rate, generate_dag_trace,
                           generate_request_demand, generate_trace,
@@ -243,9 +244,12 @@ class Scenario:
 
     # --- materialization ----------------------------------------------------
 
-    def materialize(self) -> MaterializedScenario:
+    def materialize(self, profiler: PhaseProfiler | None = None
+                    ) -> MaterializedScenario:
         """Resolve to concrete (cluster, ci, jobs, splits); cached, so the
-        same ``Scenario`` instance always yields the same job lists."""
+        same ``Scenario`` instance always yields the same job lists.
+        ``profiler`` times the job-trace generation as the span
+        ``provision/jobs``."""
         cached = self.__dict__.get("_materialized")
         if cached is not None:
             return cached
@@ -296,13 +300,15 @@ class Scenario:
                 return generate_dag_trace(s, self.dag, cluster.queues)
             return generate_trace(s, cluster.queues)
 
-        jobs = _gen(spec)
+        with span(profiler, "provision/jobs"):
+            jobs = _gen(spec)
         t0 = self.t0
         # Arrival-based splits keep DAGs whole: every task of a DAG
         # arrives at the DAG's slot (gating releases it later).
         hist = [j for j in jobs if j.arrival < t0]
         if self.eval_shift:
-            shifted = _gen(self.trace_spec(shifted=True))
+            with span(profiler, "provision/jobs"):
+                shifted = _gen(self.trace_spec(shifted=True))
             eval_jobs = [j for j in shifted if t0 <= j.arrival < self.hours]
             jobs = hist + eval_jobs
         else:

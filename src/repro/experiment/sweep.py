@@ -26,7 +26,7 @@ from repro.core.forecast import ForecastModel, forecast_labels
 from repro.core.simulator import SimCase, simulate_many
 from repro.core.types import SimResult
 from repro.serving import ServeCase, simulate_serving_many
-from repro.telemetry import Attribution, Telemetry, attribute
+from repro.telemetry import Attribution, Telemetry, attribute, span
 
 from .driver import DEFAULT_POLICIES, _fresh_faults, prepare_context
 from .registry import check_scenario_policies, make_policy
@@ -83,7 +83,8 @@ class Sweep:
     # Observability (README §Observability): when set, every cell runs
     # with this telemetry's recorder/profiler attached, each under its
     # own run label (the case label), so one sweep yields one decision
-    # trace per cell plus learn/provision/decide/execute phase totals.
+    # trace per cell plus learn/provision/decide/execute phase totals
+    # and the spans between them (policy_tables, pack, build, rows).
     # ``None`` (the default) keeps every engine on its untouched path.
     telemetry: Telemetry | None = None
 
@@ -157,23 +158,9 @@ class Sweep:
         meta: list[dict] = []
         prof = self.telemetry.profiler if self.telemetry is not None else None
         for i, sc in enumerate(scenarios):
-            if prof is not None:
-                with prof.phase("provision"):
-                    mat = sc.materialize()
-            else:
-                mat = sc.materialize()
+            mat, ctx = self._prepare(sc, names, prof)
             region_label = "+".join(sc.regions) if sc.is_geo else sc.region
             fc_label = axis_labels[i % len(axis_labels)]
-            if prof is not None:
-                with prof.phase("learn"):
-                    ctx = prepare_context(
-                        mat, names, kb_kwargs=self.kb_kwargs,
-                        backend=self.backend,
-                        forecast_quantile=self.forecast_quantile)
-            else:
-                ctx = prepare_context(mat, names, kb_kwargs=self.kb_kwargs,
-                                      backend=self.backend,
-                                      forecast_quantile=self.forecast_quantile)
             if progress is not None:
                 progress(f"prepared {region_label}/seed{sc.seed}"
                          + (f"/{fc_label}" if with_forecast else "")
@@ -188,9 +175,11 @@ class Sweep:
                     label = (f"{region_label}/s{sc.seed}/{fault_label(fm)}"
                              f"/{name}"
                              + (f"/{fc_label}" if with_forecast else ""))
+                    with span(prof, "policy_tables"):
+                        policy = make_policy(name, ctx)
                     cases.append(SimCase(
                         jobs=mat.eval_jobs, ci=ci_c, cluster=cluster_c,
-                        policy=make_policy(name, ctx), t0=mat.t0,
+                        policy=policy, t0=mat.t0,
                         horizon=horizon, faults=_fresh_faults(scf),
                         engine=sc.engine, label=label,
                         telemetry=self.telemetry.for_run(label)
@@ -201,12 +190,7 @@ class Sweep:
                         row["forecast"] = fc_label
                     meta.append(row)
         results = simulate_many(cases)       # one batched dispatch
-        rows = []
-        for m, r in zip(meta, results):
-            rows.append({**m, **r.to_dict()})
-        _attach_savings(rows, baseline)
-        return SweepResult(baseline=baseline, rows_=rows,
-                           results=results)
+        return _sweep_result(meta, results, baseline, prof)
 
     def _run_serving(self, names, baseline: str, with_forecast: bool,
                      progress) -> "SweepResult":
@@ -228,22 +212,8 @@ class Sweep:
         meta: list[dict] = []
         prof = self.telemetry.profiler if self.telemetry is not None else None
         for i, sc in enumerate(scenarios):
-            if prof is not None:
-                with prof.phase("provision"):
-                    mat = sc.materialize()
-            else:
-                mat = sc.materialize()
+            mat, ctx = self._prepare(sc, names, prof)
             fc_label = axis_labels[i % len(axis_labels)]
-            if prof is not None:
-                with prof.phase("learn"):
-                    ctx = prepare_context(
-                        mat, names, kb_kwargs=self.kb_kwargs,
-                        backend=self.backend,
-                        forecast_quantile=self.forecast_quantile)
-            else:
-                ctx = prepare_context(mat, names, kb_kwargs=self.kb_kwargs,
-                                      backend=self.backend,
-                                      forecast_quantile=self.forecast_quantile)
             horizon = sc.eval_weeks * WEEK
             demand = mat.serving.demand[mat.t0: mat.t0 + horizon]
             if progress is not None:
@@ -254,10 +224,12 @@ class Sweep:
             for name in names:
                 label = (f"{sc.region}/s{sc.seed}/{name}"
                          + (f"/{fc_label}" if with_forecast else ""))
+                with span(prof, "policy_tables"):
+                    policy = make_policy(name, ctx)
                 cases.append(ServeCase(
                     demand=demand, rate=mat.serving.rate, ci=mat.ci,
                     config=mat.serving.config,
-                    policy=make_policy(name, ctx), t0=mat.t0, label=label,
+                    policy=policy, t0=mat.t0, label=label,
                     telemetry=self.telemetry.for_run(label)
                     if self.telemetry is not None else None))
                 row = {"region": sc.region, "seed": sc.seed,
@@ -266,16 +238,34 @@ class Sweep:
                     row["forecast"] = fc_label
                 meta.append(row)
         results = simulate_serving_many(cases)
-        rows = []
-        for m, r in zip(meta, results):
-            rows.append({**m, **r.to_dict()})
-        _attach_savings(rows, baseline)
-        return SweepResult(baseline=baseline, rows_=rows, results=results)
+        return _sweep_result(meta, results, baseline, prof)
+
+    def _prepare(self, sc: Scenario, names, prof):
+        """Materialise a scenario (``provision``) and learn its knowledge
+        base (``learn``)."""
+        with span(prof, "provision"):
+            mat = sc.materialize(profiler=prof)
+        with span(prof, "learn"):
+            ctx = prepare_context(mat, names, kb_kwargs=self.kb_kwargs,
+                                  backend=self.backend,
+                                  forecast_quantile=self.forecast_quantile,
+                                  profiler=prof)
+        return mat, ctx
 
     def to_csv(self) -> str:
         """Run the sweep and export the rows as CSV
         (:meth:`SweepResult.to_csv`)."""
         return self.run().to_csv()
+
+
+def _sweep_result(meta: list[dict], results: list, baseline: str,
+                  prof) -> "SweepResult":
+    """One row per cell (its labels and results) with savings against the
+    baseline (the ``rows`` span)."""
+    with span(prof, "rows"):
+        rows = [{**m, **r.to_dict()} for m, r in zip(meta, results)]
+        _attach_savings(rows, baseline)
+    return SweepResult(baseline=baseline, rows_=rows, results=results)
 
 
 def _attach_savings(rows: list[dict], baseline: str) -> None:

@@ -6,13 +6,13 @@ from .attribution import CAUSES, Attribution, attribute
 from .events import (EVENT_KINDS, MemoryRecorder, SlotEventTracker,
                      Telemetry, TraceEvent, TraceRecorder,
                      emit_fault_events)
-from .profiler import PHASES, PhaseProfiler
+from .profiler import PHASES, PhaseProfiler, span
 from .report import explain
 
 __all__ = [
     "CAUSES", "Attribution", "attribute",
     "EVENT_KINDS", "MemoryRecorder", "SlotEventTracker", "Telemetry",
     "TraceEvent", "TraceRecorder", "emit_fault_events",
-    "PHASES", "PhaseProfiler",
+    "PHASES", "PhaseProfiler", "span",
     "explain",
 ]

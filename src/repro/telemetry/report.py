@@ -18,7 +18,7 @@ def explain(result, baseline=None, *, recorder: MemoryRecorder | None = None,
 
     ``baseline`` adds the cause decomposition of the carbon delta;
     ``recorder`` adds event counts (restricted to ``run``'s label when
-    given); ``profiler`` adds the phase table."""
+    given); ``profiler`` adds the phase, span and counter table."""
     lines = [f"run: {result.policy}",
              f"  carbon      {result.carbon_g:,.1f} g",
              f"  energy      {result.energy_kwh:,.3f} kWh",
@@ -49,7 +49,8 @@ def explain(result, baseline=None, *, recorder: MemoryRecorder | None = None,
         else:
             lines.append("events: none recorded")
 
-    if profiler is not None and profiler.seconds:
+    if profiler is not None and (profiler.seconds or profiler.spans
+                                 or profiler.counters):
         lines.append("")
         lines.append("phases:")
         lines.extend("  " + ln for ln in profiler.table().splitlines())

@@ -375,14 +375,174 @@ def test_profiler_brackets_and_summary():
         pass
     with prof.phase("decide"):
         pass
+    with prof.phase("pack"):
+        pass
+    with prof.phase("decide/wait"):
+        pass
     with prof.phase("execute", sync=np.zeros(3)):
         pass
     s = prof.summary()
-    assert list(s) == ["decide", "execute"]
-    assert s["decide"]["calls"] == 2
-    assert abs(sum(d["share"] for d in s.values()) - 1.0) < 1e-9
+    # phases first, each with the spans inside it, then the spans
+    # between phases
+    assert list(s["timers"]) == ["decide", "decide/wait", "execute", "pack"]
+    assert s["timers"]["decide"]["calls"] == 2
+    tops = [d for n, d in s["timers"].items() if "/" not in n]
+    assert abs(sum(d["share"] for d in tops) - 1.0) < 1e-9
+    assert s["counters"] == {}
     assert prof.total() > 0
-    assert "decide" in prof.table()
+    assert "decide" in prof.table() and "\n  decide/wait" in prof.table()
+
+
+def test_phase_names_book_into_seconds_and_others_into_spans():
+    from repro.telemetry import PHASES
+
+    prof = PhaseProfiler()
+    for name in PHASES + ("pack", "decide/wait", "learn/oracle"):
+        with prof.phase(name):
+            pass
+    prof.add("rows", 0.5)
+    assert set(prof.seconds) == set(PHASES)
+    assert set(prof.spans) == {"pack", "decide/wait", "learn/oracle", "rows"}
+    assert prof.spans["rows"] == {"seconds": 0.5, "calls": 1}
+    assert prof.total() == sum(prof.seconds.values())
+
+
+def test_count_adds_to_counters():
+    prof = PhaseProfiler()
+    prof.count("scan_slots", 168)
+    prof.count("scan_slots", np.int64(24))
+    prof.count("chunks")
+    assert prof.counters == {"scan_slots": 192, "chunks": 1}
+    assert prof.summary()["counters"] == prof.counters
+    assert "scan_slots" in prof.table() and "192" in prof.table()
+
+
+def test_span_without_profiler_is_a_shared_no_op():
+    from repro.telemetry import span
+
+    assert span(None, "decide") is span(None, "pack")
+    with span(None, "decide/wait", sync=np.zeros(3)):
+        pass
+
+
+# --- spans and counters on the scan path -----------------------------------
+
+SCAN_SPANS = {"pack", "policy_tables", "build", "provision/jobs",
+              "learn/oracle", "decide/tables", "decide/upload",
+              "decide/wait", "decide/fetch"}
+SCAN_COUNTERS = {"scan_slots", "scan_slots_past_end", "h2d_bytes",
+                 "d2h_bytes"}
+SCAN_POLICIES = ["carbon-agnostic", "wait-awhile", "carbonflex-mpc"]
+
+
+def _scan_run(telemetry=None):
+    from repro.experiment import run
+
+    res = run(Scenario(capacity=8, learn_weeks=1, family="alibaba", seed=101,
+                       engine="scan"), SCAN_POLICIES, telemetry=telemetry)
+    return [r.to_dict() for p in SCAN_POLICIES for r in res.weekly[p]]
+
+
+def _scan_sweep(telemetry=None):
+    sw = Sweep(base=Scenario(capacity=8, learn_weeks=1, family="alibaba",
+                             engine="scan"),
+               seeds=[1, 2], policies=SCAN_POLICIES, telemetry=telemetry)
+    return sw.run().to_json()
+
+
+def _geo_run(telemetry=None):
+    from repro.experiment import run
+
+    pols = ["geo-static", "geo-greedy", "geo-flex"]
+    res = run(Scenario(regions=("south-australia", "california"),
+                       capacity=10, learn_weeks=1, seed=3, family="alibaba",
+                       engine="scan"), pols, telemetry=telemetry)
+    return [r.to_dict() for p in pols for r in res.weekly[p]]
+
+
+SCAN_CALLS = {"run": (_scan_run, SCAN_SPANS),
+              "sweep": (_scan_sweep, SCAN_SPANS | {"rows"}),
+              "geo": (_geo_run, SCAN_SPANS - {"learn/oracle"})}
+
+
+@pytest.fixture(scope="module")
+def scan_profiles():
+    """Each scan-path call once detached and once with a profiler: (the
+    results both ways, the profiler, the profiled call's wall seconds)."""
+    import time
+
+    out = {}
+    for name, (call, _) in SCAN_CALLS.items():
+        plain = call()
+        prof = PhaseProfiler()
+        t = time.perf_counter()
+        profiled = call(Telemetry(profiler=prof))
+        out[name] = (plain, profiled, prof, time.perf_counter() - t)
+    return out
+
+
+@pytest.mark.parametrize("call", sorted(SCAN_CALLS))
+def test_scan_path_opens_every_span(scan_profiles, call):
+    prof = scan_profiles[call][2]
+    assert set(prof.seconds) == {"provision", "learn", "decide", "execute"}
+    assert SCAN_CALLS[call][1] <= set(prof.spans)
+    assert set(prof.summary()["timers"]) == set(prof.seconds) | set(prof.spans)
+    table = prof.table()
+    assert all(n.split("/")[-1] in table for n in SCAN_CALLS[call][1])
+
+
+@pytest.mark.parametrize("call", sorted(SCAN_CALLS))
+def test_scan_spans_fit_inside_wall_time_and_decide(scan_profiles, call):
+    _, _, prof, wall = scan_profiles[call]
+    tops = sum(d["seconds"] for n, d in prof.spans.items() if "/" not in n)
+    assert prof.total() + tops <= wall
+    parts = sum(d["seconds"] for n, d in prof.spans.items()
+                if n.startswith("decide/"))
+    assert 0 < parts <= prof.seconds["decide"]
+    assert prof.spans["provision/jobs"]["seconds"] <= prof.seconds["provision"]
+
+
+@pytest.mark.parametrize("call", sorted(SCAN_CALLS))
+def test_scan_counters(scan_profiles, call):
+    c = scan_profiles[call][2].counters
+    assert set(c) == SCAN_COUNTERS
+    assert c["scan_slots"] > 0
+    assert 0 <= c["scan_slots_past_end"] <= c["scan_slots"]
+    assert c["h2d_bytes"] > 0 and c["d2h_bytes"] > 0
+
+
+@pytest.mark.parametrize("call", sorted(SCAN_CALLS))
+def test_scan_results_bit_equal_with_profiler(scan_profiles, call):
+    plain, profiled, _, _ = scan_profiles[call]
+    assert profiled == plain
+
+
+def test_tile_with_differing_profilers_splits_decide():
+    """Members of one vmapped tile with their own profilers (or none)
+    each get an even share of the tile's decide time and count the
+    tile's slots; no spans open, and results do not change."""
+    from repro.core import SimCase, simulate_many
+
+    mat = tiny()
+
+    def cases(tels):
+        return [SimCase(jobs=mat.eval_jobs, ci=mat.ci, cluster=mat.cluster,
+                        policy=baselines.WaitAwhilePolicy(), t0=mat.t0,
+                        horizon=WEEK, engine="scan", telemetry=t)
+                for t in tels]
+
+    plain = [r.to_dict() for r in simulate_many(cases([None] * 3))]
+    p1, p2 = PhaseProfiler(), PhaseProfiler()
+    got = simulate_many(cases([Telemetry(profiler=p1), None,
+                               Telemetry(profiler=p2)]))
+    assert [r.to_dict() for r in got] == plain
+    for p in (p1, p2):
+        assert p.calls["decide"] == 1
+        assert not any(n.startswith("decide/") for n in p.spans)
+        assert {"pack", "policy_tables", "build"} <= set(p.spans)
+    assert p1.seconds["decide"] == p2.seconds["decide"]
+    assert p1.counters["scan_slots"] == p2.counters["scan_slots"] > 0
+    assert p1.counters["d2h_bytes"] == p2.counters["d2h_bytes"] > 0
 
 
 def test_run_and_sweep_surface_phase_profile():
