@@ -1432,7 +1432,7 @@ def simulate_scan(jobs, ci, cluster, policy, t0: int = 0,
     prof = telemetry.profiler if telemetry is not None else None
     if packed is None:
         with span(prof, "pack"):
-            packed = _packed_for(jobs)
+            packed = _packed_for(jobs, prof)
     kind = native_kind(policy, cluster, faults)
     if (kind == "mpc-scale" and telemetry is not None
             and telemetry.recorder is not None):
@@ -1481,7 +1481,7 @@ def simulate_many_scan(cases: Sequence) -> list[SimResult]:
             telemetry = getattr(case, "telemetry", None)
             prof = telemetry.profiler if telemetry is not None else None
             with span(prof, "pack"):
-                packed = _packed_for(case.jobs)
+                packed = _packed_for(case.jobs, prof)
             kind = native_kind(case.policy, case.cluster, case.faults)
             if (kind == "mpc-scale" and telemetry is not None
                     and telemetry.recorder is not None):

@@ -131,8 +131,31 @@ def apply_slot(active: list[ActiveJob], alloc: dict[int, int]) -> None:
 # The vectorised simulator engine keeps per-job state in flat arrays; the
 # helpers below run Algorithm 3 against those arrays without building
 # ActiveJob lists or per-slot (job, scale) Python enumerations.  Candidate
-# (p, k) pairs per job are static — they depend only on the profile — so
-# they are concatenated once per packed-job build and gathered per slot.
+# (p, k) pairs per job are static — they depend only on ``(k_min,
+# profile)`` — so they are built once per distinct profile, concatenated
+# in row order once per packed-job build, and gathered per slot.
+
+
+def profile_groups(jobs: list[Job]) -> tuple[list[Job], np.ndarray]:
+    """Group ``jobs`` by the content of ``(k_min, profile)``, the only
+    inputs of ``Job.throughput``/``marginal``/``elasticity``.
+
+    Returns one representative job per group (in order of first
+    appearance) and each job's int64 group index.  The key holds the
+    profile's dtype, shape and bytes, so jobs of one group have bit-equal
+    inputs and every per-job table can be computed once per group."""
+    index: dict[tuple, int] = {}
+    reps: list[Job] = []
+    row_group = []
+    for job in jobs:
+        prof = np.asarray(job.profile)
+        key = (job.k_min, prof.dtype.str, prof.shape, prof.tobytes())
+        g = index.get(key)
+        if g is None:
+            g = index[key] = len(reps)
+            reps.append(job)
+        row_group.append(g)
+    return reps, np.array(row_group, dtype=np.int64)
 
 
 @dataclasses.dataclass
@@ -148,22 +171,30 @@ class EntryBlocks:
     cnt: np.ndarray              # int64 per-row pair count
 
     @classmethod
-    def build(cls, jobs: list[Job]) -> "EntryBlocks":
+    def build(cls, jobs: list[Job],
+              groups: tuple[list[Job], np.ndarray] | None = None
+              ) -> "EntryBlocks":
+        """Blocks for ``jobs``: each ``(k_min, profile)`` group's pairs are
+        built once from its representative and gathered by row.
+        ``groups`` is ``profile_groups(jobs)`` when the caller has it."""
+        reps, row_group = profile_groups(jobs) if groups is None else groups
         ps, ks, off, cnt = [], [], [], []
         pos = 0
-        for job in jobs:
-            pairs = [(job.marginal(k), k)
-                     for k in range(job.k_min, job.k_max + 1)
-                     if job.marginal(k) > 0]
+        for job in reps:
+            pairs = [(p, k) for k in range(job.k_min, job.k_max + 1)
+                     if (p := job.marginal(k)) > 0]
             off.append(pos)
             cnt.append(len(pairs))
             pos += len(pairs)
             ps.extend(p for p, _ in pairs)
             ks.extend(k for _, k in pairs)
-        return cls(np.array(ps, dtype=np.float64),
-                   np.array(ks, dtype=np.int64),
-                   np.array(off, dtype=np.int64),
-                   np.array(cnt, dtype=np.int64))
+        per_group = cls(np.array(ps, dtype=np.float64),
+                        np.array(ks, dtype=np.int64),
+                        np.array(off, dtype=np.int64),
+                        np.array(cnt, dtype=np.int64))
+        flat_p, flat_k, _ = per_group.gather(row_group)
+        cnt = per_group.cnt[row_group]
+        return cls(flat_p, flat_k, np.cumsum(cnt) - cnt, cnt)
 
     def gather(self, rows: np.ndarray):
         """(P, K, R) candidate arrays for ``rows``, preserving row order."""

@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from operator import attrgetter
 from typing import Iterable, Sequence
 
 import numpy as np
@@ -50,10 +51,11 @@ from .carbon import CarbonService, MultiRegionCarbonService
 from .faults import (FaultModel, FaultProcess,  # noqa: F401  (re-export)
                      ensure_fault_process)
 from .policy import Policy
-from .scheduling import ActiveJob, EntryBlocks, apply_slot
+from .scheduling import ActiveJob, EntryBlocks, apply_slot, profile_groups
 from .types import (ClusterConfig, GeoCluster, Job, ResilienceMetrics,
                     SimResult, SlotLog)
-from ..telemetry import (SlotEventTracker, Telemetry, emit_fault_events)
+from ..telemetry import (PhaseProfiler, SlotEventTracker, Telemetry,
+                         emit_fault_events)
 
 _EPS = 1e-9
 
@@ -111,28 +113,42 @@ class PackedJobs:
 
     __slots__ = ("jobs", "n", "job_ids", "arrival", "length", "queue",
                  "k_min", "k_max", "deadline", "elast", "power", "comm",
-                 "thr_tab", "blocks", "id2row", "has_deps", "dl_span",
-                 "pred0", "succ_ptr", "succ_rows")
+                 "thr_tab", "blocks", "n_profiles", "id2row", "has_deps",
+                 "dl_span", "pred0", "succ_ptr", "succ_rows")
 
     def __init__(self, jobs_sorted: list[Job]) -> None:
         self.jobs = jobs_sorted
         n = self.n = len(jobs_sorted)
-        self.job_ids = np.array([j.job_id for j in jobs_sorted], dtype=np.int64)
-        self.arrival = np.array([j.arrival for j in jobs_sorted], dtype=np.int64)
-        self.length = np.array([j.length for j in jobs_sorted], dtype=np.float64)
-        self.queue = np.array([j.queue for j in jobs_sorted], dtype=np.int64)
-        self.k_min = np.array([j.k_min for j in jobs_sorted], dtype=np.int64)
-        self.k_max = np.array([j.k_max for j in jobs_sorted], dtype=np.int64)
-        self.deadline = np.array([j.deadline for j in jobs_sorted], dtype=np.int64)
-        self.elast = np.array([j.elasticity() for j in jobs_sorted], dtype=np.float64)
-        self.power = np.array([j.power for j in jobs_sorted], dtype=np.float64)
-        self.comm = np.array([j.comm_size for j in jobs_sorted], dtype=np.float64)
+
+        def col(name: str, dtype) -> np.ndarray:
+            return np.fromiter(map(attrgetter(name), jobs_sorted),
+                               dtype=dtype, count=n)
+
+        self.job_ids = col("job_id", np.int64)
+        self.arrival = col("arrival", np.int64)
+        self.length = col("length", np.float64)
+        self.queue = col("queue", np.int64)
+        self.k_min = col("k_min", np.int64)
+        self.power = col("power", np.float64)
+        self.comm = col("comm_size", np.float64)
+        # Job.deadline, vectorised (equal for every finite length)
+        self.deadline = (self.arrival + np.ceil(self.length).astype(np.int64)
+                         + col("delay", np.int64))
+        # Profile-derived tables: one row per distinct (k_min, profile),
+        # filled by the representative's own Job calls, gathered by row.
+        groups = reps, row_group = profile_groups(jobs_sorted)
+        self.n_profiles = len(reps)
+        self.k_max = np.array([j.k_max for j in reps],
+                              dtype=np.int64)[row_group]
+        self.elast = np.array([j.elasticity() for j in reps],
+                              dtype=np.float64)[row_group]
         kmax_g = int(self.k_max.max()) if n else 0
-        self.thr_tab = np.zeros((n, kmax_g + 1))
-        for i, job in enumerate(jobs_sorted):
+        tab = np.zeros((len(reps), kmax_g + 1))
+        for g, job in enumerate(reps):
             for k in range(1, kmax_g + 1):
-                self.thr_tab[i, k] = job.throughput(k)
-        self.blocks = EntryBlocks.build(jobs_sorted)
+                tab[g, k] = job.throughput(k)
+        self.thr_tab = tab[row_group]
+        self.blocks = EntryBlocks.build(jobs_sorted, groups)
         self.id2row = {j.job_id: i for i, j in enumerate(jobs_sorted)}
         # Precedence structure (DAG workloads, core/dag.py): initial
         # in-degree per row plus a successor CSR so parent completions can
@@ -187,14 +203,17 @@ _PACK_CACHE: dict[int, tuple[tuple[int, ...], PackedJobs]] = {}
 _PACK_CACHE_MAX = 8
 
 
-def _packed_for(jobs: list[Job]) -> PackedJobs:
+def _packed_for(jobs: list[Job],
+                prof: PhaseProfiler | None = None) -> PackedJobs:
     """Memoised PackedJobs for a job list (throughput tables and entry
     blocks are pure functions of the jobs, so re-simulating the same trace
     — e.g. one run per policy in a sweep — packs once).  The cache keys on
     the element identities plus the scalar fields the tables are built
     from, so rebuilt lists, ``dataclasses.replace``d jobs, and in-place
     field edits all repack.  (In-place mutation of a ``profile`` array's
-    *contents* is the one change this cannot see.)"""
+    *contents* is the one change this cannot see.)  A miss counts
+    ``pack_builds``, ``pack_jobs`` and ``pack_profile_tables`` on ``prof``;
+    a hit counts nothing."""
     key = id(jobs)
     sig = tuple((id(j), j.arrival, j.length, j.delay, j.queue, j.k_min,
                  j.power, j.comm_size, id(j.profile), j.deps) for j in jobs)
@@ -202,6 +221,10 @@ def _packed_for(jobs: list[Job]) -> PackedJobs:
     if hit is not None and hit[0] == sig:
         return hit[1]
     packed = PackedJobs(sorted(jobs, key=lambda j: (j.arrival, j.job_id)))
+    if prof is not None:
+        prof.count("pack_builds")
+        prof.count("pack_jobs", packed.n)
+        prof.count("pack_profile_tables", packed.n_profiles)
     if len(_PACK_CACHE) >= _PACK_CACHE_MAX:
         _PACK_CACHE.pop(next(iter(_PACK_CACHE)))
     _PACK_CACHE[key] = (sig, packed)

@@ -431,7 +431,8 @@ SCAN_SPANS = {"pack", "policy_tables", "build", "provision/jobs",
               "learn/oracle", "decide/tables", "decide/upload",
               "decide/wait", "decide/fetch"}
 SCAN_COUNTERS = {"scan_slots", "scan_slots_past_end", "h2d_bytes",
-                 "d2h_bytes"}
+                 "d2h_bytes", "pack_builds", "pack_jobs",
+                 "pack_profile_tables"}
 SCAN_POLICIES = ["carbon-agnostic", "wait-awhile", "carbonflex-mpc"]
 
 
@@ -509,6 +510,8 @@ def test_scan_counters(scan_profiles, call):
     assert c["scan_slots"] > 0
     assert 0 <= c["scan_slots_past_end"] <= c["scan_slots"]
     assert c["h2d_bytes"] > 0 and c["d2h_bytes"] > 0
+    # every call materialises fresh job lists, so each packs at least once
+    assert 1 <= c["pack_builds"] <= c["pack_profile_tables"] <= c["pack_jobs"]
 
 
 @pytest.mark.parametrize("call", sorted(SCAN_CALLS))
