@@ -9,12 +9,15 @@ Four numbers are compared, each against its limit (``LIMITS``):
 - ``decision_mismatch``: per-job completion slot, waiting slots,
   violation flag, per-slot servers used, jobs running and queued and
   the learned-state count that differ from
-  the plain reference;
+  the plain reference; in a geo cluster also each job's final region,
+  the region names and the migration count;
 - ``energy_rel_err`` / ``carbon_rel_err``: the largest relative gap of
-  a total or a per-slot value of energy / carbon.
+  a total or a per-slot value of energy / carbon; in a geo cluster also
+  of each region's total and of the migrations' carbon.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import numpy as np
@@ -67,6 +70,10 @@ def _rel(a: float, b: float) -> float:
 
 _JOB_INT = ("completion", "violations")
 _SLOT_INT = ("slot", "provisioned", "used", "running", "queued")
+# per-job and per-region fields only a geo cluster's results carry
+_GEO_JOB = ("final_region",)
+_GEO_REGION = (("region_energy_kwh", "energy_rel_err"),
+               ("region_carbon_g", "carbon_rel_err"))
 
 
 class Tally:
@@ -115,7 +122,7 @@ class Tally:
         """Compare one program result (``SimResult.to_dict`` with per-job
         and per-slot detail) with the reference's."""
         bad = int(prog.get("policy") != ref["policy"])
-        for key in _JOB_INT + ("wait_slots",):
+        for key in _JOB_INT + ("wait_slots",) + _GEO_JOB:
             if key not in ref:
                 continue
             pa, ra = prog.get(key), ref[key]
@@ -132,9 +139,11 @@ class Tally:
                       f"{what} slot {r['slot']}")
             self._err("carbon_rel_err", p["carbon_g"], r["carbon_g"],
                       f"{what} slot {r['slot']}")
+        if "regions" in ref:
+            bad += self._geo(prog, ref, what)
         if bad:
             keys = [k for k in _JOB_INT + ("wait_slots", "policy", "slots")
-                    if k in ref]
+                    + _GEO_JOB + ("regions", "migrations") if k in ref]
             where = first_diff({k: prog.get(k) for k in keys},
                                {k: ref[k] for k in keys})
             self.decisions(bad, f"{what} (first at {where})")
@@ -142,6 +151,21 @@ class Tally:
                   f"{what} total")
         self._err("carbon_rel_err", prog["carbon_g"], ref["carbon_g"],
                   f"{what} total")
+
+    def _geo(self, prog: dict, ref: dict, what: str) -> int:
+        """A geo result's extras: the regions and the migration count as
+        decisions, each region's energy and carbon and the migrations'
+        carbon as values.  Returns the decisions that differ."""
+        bad = int(prog.get("regions") != ref["regions"])
+        bad += abs(int(prog.get("migrations", -1)) - ref["migrations"])
+        for key, err in _GEO_REGION:
+            pv, rv = prog.get(key) or [], ref[key]
+            bad += abs(len(pv) - len(rv))
+            for r, (a, b) in enumerate(zip(pv, rv)):
+                self._err(err, a, b, f"{what} {key}[{r}]")
+        self._err("carbon_rel_err", prog.get("migration_carbon_g", math.nan),
+                  ref["migration_carbon_g"], f"{what} migration carbon")
+        return bad
 
     def passed(self) -> bool:
         return all(v <= LIMITS[k] for k, v in self.values.items())
@@ -155,11 +179,15 @@ class Tally:
                 for k, v in self.values.items()}
 
 
-def world_mismatch(mat, world) -> tuple[int, str]:
+def world_mismatch(mat, world, migration=None) -> tuple[int, str]:
     """Fields of the program's materialised world (a
     ``MaterializedScenario``) that differ from the benchmark's own
     generator: every job's id, arrival, length, queue, slack, k_min,
-    power, communication size and profile, and every CI value."""
+    power, communication size and profile, and every CI value.  A geo
+    world also has every region's CI trace compared, its region names,
+    its capacity split, the home region of each evaluated job, and its
+    migration costs against ``migration`` (a dataclass of the reference
+    whose fields are named as the program's)."""
     bad = 0
     pj, rj = mat.jobs, world.jobs
     bad += abs(len(pj) - len(rj))
@@ -172,7 +200,11 @@ def world_mismatch(mat, world) -> tuple[int, str]:
         if not np.array_equal(np.asarray(a.profile), b.profile):
             bad += 1
     bad += abs(len(mat.eval_jobs) - len(world.eval_jobs))
-    traces = [np.asarray(mat.ci.trace)]
+    if mat.mci is None:
+        traces = [np.asarray(mat.ci.trace)]
+    else:
+        traces = [np.asarray(s.trace) for s in mat.mci.services]
+        bad += _geo_mismatch(mat.geo, world, migration)
     bad += abs(len(traces) - len(world.traces))
     for p, r in zip(traces, world.traces):
         if p.shape != r.shape:
@@ -182,12 +214,28 @@ def world_mismatch(mat, world) -> tuple[int, str]:
     return bad, f"{len(rj)} jobs, {len(world.traces)} CI trace(s)"
 
 
+def _geo_mismatch(geo, world, migration) -> int:
+    """The geo cluster's layout against the reference world's."""
+    n_reg = len(world.regions)
+    bad = int(tuple(geo.regions) != tuple(world.regions))
+    caps = tuple(geo.capacities)
+    bad += abs(len(caps) - n_reg) + sum(
+        1 for a, b in zip(caps, world.capacities) if a != b)
+    bad += sum(1 for row in range(len(world.eval_jobs))
+               if geo.home_region(row) != row % n_reg)
+    want = dataclasses.asdict(migration)
+    bad += sum(1 for k, v in want.items()
+               if getattr(geo.migration, k, None) != v)
+    return bad
+
+
 def digest(out: dict, plan) -> dict:
-    """What the check keeps of one request's output: its index, the
-    compared results as dicts, the result count, and the run's scenario
-    and knowledge-base size (None for a sweep)."""
+    """What the check keeps of one request's output: its serial number
+    and compared indices, the compared results as dicts, the result
+    count, and the run's scenario and knowledge-base size (None for a
+    sweep)."""
     res = out["results"]
-    return {"compared": plan.compared(out["n"]),
+    return {"n": out["n"], "compared": plan.compared(out["n"]),
             "results": {i: res[i].to_dict(include_per_job=True,
                                           include_slots=True)
                         for i in plan.compared(out["n"]) if i < len(res)},
@@ -202,13 +250,15 @@ def check_request(d: dict, plan, ref, what: str = "") -> Tally:
     if d["n_results"] != plan.units_per_request:
         tally.decisions(abs(plan.units_per_request - d["n_results"]),
                         f"{what} result count")
+    n = d["n"]
     for i in d["compared"]:
         if i in d["results"]:
-            tally.result(d["results"][i], ref.result(i), f"{what} cell {i}")
-    world = plan.worlds[0]
+            tally.result(d["results"][i], ref.result(i, n),
+                         f"{what} cell {i}")
+    world = plan.worlds[plan.world_index(0, n)]
     if d["scenario"] is not None:
         bad, where = world_mismatch(d["scenario"].materialize(),
-                                    ref.world(world))
+                                    ref.world(world), ref.migration)
         tally.provision(bad, f"{what} {where}")
     if d["kb_size"] is not None and \
             d["kb_size"] != ref.learned_states(world):

@@ -7,18 +7,23 @@ what-if query evaluates, and the band of jobs an evaluated week holds.
 A traffic mix (``traffic/<name>.json``) fixes what one request asks:
 
 - ``"call": "run"``: one ``run()`` of the configuration's policies over
-  one world;
+  one world; with ``worlds_per_run: W`` the run draws W worlds and
+  request ``n`` uses world ``n mod W``, so a window's rate averages a
+  fixed cycle of worlds and not one world's work;
 - ``"call": "sweep"``: one ``Sweep.run()`` over ``sweep_regions`` x
   ``seeds_per_request`` worlds x an optional noisy ``forecasts`` grid x
   its own ``policies``.
 
 Any traffic key named like a ``scenario`` field (``learn_weeks``, ...)
-overrides the configuration's.  World seeds, forecast seeds and the
-cells the check compares all derive from ``--seed``; a sweep's compared
-cells are drawn anew for every request, so a window's check covers most
-of the grid.  A world seed is kept only when its evaluated weeks hold a
-job count inside the configuration's ``eval_jobs_band`` (the central 80%
-of the generator's own distribution), so seeds do like amounts of work.
+overrides the configuration's.  A ``scenario`` with ``regions`` (two or
+more) is a geo-distributed deployment: its worlds span those regions,
+and its ``migration`` costs, when given, go to every ``Scenario``.
+World seeds, forecast seeds and the cells the check compares all derive
+from ``--seed``; a sweep's compared cells are drawn anew for every
+request, so a window's check covers most of the grid.  A world seed is
+kept only when its evaluated weeks hold a job count inside the
+configuration's ``eval_jobs_band`` (the central 80% of the generator's
+own distribution), so seeds do like amounts of work.
 """
 from __future__ import annotations
 
@@ -45,11 +50,20 @@ class WorldSpec:
     eval_weeks: int
     forecast: tuple[float, int] | None = None      # (sigma, seed) if noisy
 
+    @property
+    def is_geo(self) -> bool:
+        """A world over two or more regions is one geo cluster."""
+        return len(self.regions) > 1
+
     def scenario_kwargs(self) -> dict:
         kw = dict(family=self.family, capacity=self.capacity,
                   utilization=self.utilization,
                   learn_weeks=self.learn_weeks, eval_weeks=self.eval_weeks,
-                  seed=self.seed, region=self.regions[0])
+                  seed=self.seed)
+        if self.is_geo:
+            kw["regions"] = self.regions
+        else:
+            kw["region"] = self.regions[0]
         return kw
 
     def world_kwargs(self) -> dict:
@@ -64,12 +78,26 @@ class Plan:
     """What every request of a run does, and what the check compares."""
 
     call: str                       # "run" | "sweep"
-    worlds: list[WorldSpec]         # sweep: in Sweep.scenarios() order
+    worlds: list[WorldSpec]         # run: its cycle; sweep: Sweep's order
     policies: tuple[str, ...]
     units_per_request: int          # policy-weeks (run) or cells (sweep)
     n_compare: int                  # results of a request compared
     unit: str
     seed: int
+    migration: dict | None = None   # MigrationModel fields of a geo world
+
+    @property
+    def cycle(self) -> int:
+        """Requests that together cover every world once."""
+        return len(self.worlds) if self.call == "run" else 1
+
+    def world_index(self, i: int, n: int = 0) -> int:
+        """Index in ``worlds`` of result ``i`` of the ``n``-th request: a
+        run cycles its worlds request by request; a sweep's results are
+        worlds outer, policies inner, as ``Sweep`` orders them."""
+        if self.call == "run":
+            return n % len(self.worlds)
+        return i // len(self.policies)
 
     def compared(self, n: int) -> list[int]:
         """Result indices the check compares in the run's ``n``-th
@@ -81,10 +109,9 @@ class Plan:
         return sorted(int(i) for i in rng.choice(
             self.units_per_request, size=self.n_compare, replace=False))
 
-    def cell(self, i: int) -> tuple[WorldSpec, str]:
-        """World and policy of result ``i`` (worlds outer, policies
-        inner, as ``run()`` and ``Sweep`` order them)."""
-        return self.worlds[i // len(self.policies)], \
+    def cell(self, i: int, n: int = 0) -> tuple[WorldSpec, str]:
+        """World and policy of result ``i`` of the ``n``-th request."""
+        return self.worlds[self.world_index(i, n)], \
             self.policies[i % len(self.policies)]
 
 
@@ -135,14 +162,23 @@ def make_plan(config: dict, traffic: dict, seed: int) -> Plan:
                          learn_weeks=scen["learn_weeks"],
                          eval_weeks=scen["eval_weeks"], forecast=fc)
 
-    home = (scen["region"],)
+    geo = "regions" in scen
+    if geo:
+        home = tuple(scen["regions"])
+        if len(home) < 2:
+            raise ValueError("a geo deployment needs two or more regions")
+    else:
+        home = (scen["region"],)
     if call == "run":
         policies = tuple(traffic.get("policies", config["policies"]))
-        worlds = [spec(home, s) for s in _world_seeds(1, seed, scen, band)]
+        worlds = [spec(home, s) for s in _world_seeds(
+            traffic.get("worlds_per_run", 1), seed, scen, band)]
         units = len(policies) * scen["eval_weeks"]
         n_cmp = units
         unit = "policy-weeks"
     elif call == "sweep":
+        if geo:
+            raise ValueError("a sweep takes single-region worlds")
         policies = tuple(traffic["policies"])
         regions = tuple(traffic.get("sweep_regions", home))
         seeds = _world_seeds(traffic.get("seeds_per_request", 1), seed,
@@ -162,7 +198,7 @@ def make_plan(config: dict, traffic: dict, seed: int) -> Plan:
         raise ValueError(f"unknown traffic call {call!r}")
     return Plan(call=call, worlds=worlds, policies=policies,
                 units_per_request=units, n_compare=n_cmp, unit=unit,
-                seed=seed)
+                seed=seed, migration=scen.get("migration"))
 
 
 class Requests:
@@ -172,11 +208,14 @@ class Requests:
 
     def __init__(self, plan: Plan):
         from repro.core.forecast import NoisyForecast
+        from repro.core.types import MigrationModel
         from repro.experiment import Scenario, Sweep, run
 
         self.plan = plan
         self._Scenario, self._Sweep, self._run = Scenario, Sweep, run
         self._Noisy = NoisyForecast
+        self._extra = {} if plan.migration is None else \
+            {"migration": MigrationModel(**plan.migration)}
         self._sent = 0
 
     def send(self, telemetry=None) -> dict:
@@ -187,7 +226,9 @@ class Requests:
         n = self._sent
         self._sent += 1
         if p.call == "run":
-            sc = self._Scenario(engine="scan", **p.worlds[0].scenario_kwargs())
+            sc = self._Scenario(engine="scan", **self._extra,
+                                **p.worlds[p.world_index(0, n)]
+                                .scenario_kwargs())
             res = self._run(sc, p.policies, telemetry=telemetry)
             return {"n": n, "results": [r for pol in p.policies
                                         for r in res.weekly[pol]],

@@ -2,7 +2,8 @@
 
 Every run: name the device (and stop with no result unless it is a TPU
 with the chips the cell asks for), set up, send the cell's request once
-to warm up its shapes, send it again in a closed loop with one client
+(a run that cycles worlds: once for each world) to warm up its shapes,
+send it again in a closed loop with one client
 for ``--seconds`` (the window closes at the end of the last request
 that started inside it),
 read the device's peak memory, and check every request's results
@@ -266,7 +267,7 @@ def measure(workload: str, seed: int, seconds: float, traced: bool, *,
     requests = generator.Requests(plan)
     log(f"{workload}: {plan.call} of {len(plan.policies)} policies = "
         f"{plan.units_per_request} {plan.unit} per request over "
-        f"{len(plan.worlds)} world(s); "
+        f"{len(plan.worlds)} world(s), a cycle of {plan.cycle} request(s); "
         f"device {device}")
 
     watch = Watch()
@@ -277,7 +278,8 @@ def measure(workload: str, seed: int, seconds: float, traced: bool, *,
     batched = scan_engine._single_chunk_batch
     batched.clear_cache()
     with watch.active():
-        outs.append(requests.send())                      # warm-up
+        for _ in range(plan.cycle):                       # warm-up
+            outs.append(requests.send())
         tiles = batched._cache_size()
         setup_s = time.perf_counter() - t_start
         compiles_setup = watch.compiles

@@ -8,7 +8,8 @@ None) and ``traced_units``.
 Each returns None when its cell has nothing to read."""
 from __future__ import annotations
 
-SCAN_PROGRAMS = ("jit__single_chunk", "jit__single_chunk_batch")
+SCAN_PROGRAMS = ("jit__single_chunk", "jit__single_chunk_batch",
+                 "jit__geo_chunk")
 
 
 def phase_ms(ctx: dict, phase: str, call: str) -> float | None:

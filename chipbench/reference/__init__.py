@@ -1,11 +1,12 @@
 """The plain reference the benchmark compares the program with: its own
-world generator (``world``) and slot-loop simulation (``sim``).  It
-imports nothing of the program."""
+world generator (``world``) and slot-loop simulations (``sim`` for one
+region, ``geo`` for a geo-distributed cluster).  It imports nothing of
+the program."""
 from __future__ import annotations
 
 import numpy as np
 
-from . import sim
+from . import geo, sim
 from .world import WEEK, Forecast, build_world
 
 KB_POLICIES = ("carbonflex-mpc", "carbonflex-scale")
@@ -32,8 +33,9 @@ class Reference:
     def __init__(self, plan, dtype=np.float64):
         self.plan = plan
         self.dtype = dtype
+        self.migration = geo.Migration(**(plan.migration or {}))
         self._worlds: dict = {}
-        self._results: dict[int, dict] = {}
+        self._results: dict[tuple, dict] = {}
 
     def world(self, ws):
         key = (ws.regions, ws.seed)
@@ -44,18 +46,26 @@ class Reference:
         return self._worlds[key]
 
     @property
-    def computed(self) -> list[int]:
-        """Result indices simulated so far."""
+    def computed(self) -> list[tuple]:
+        """(world, policy) pairs simulated so far."""
         return list(self._results)
 
-    def result(self, i: int) -> dict:
-        if i not in self._results:
-            self._results[i] = self._simulate(i)
-        return self._results[i]
+    def result(self, i: int, n: int = 0) -> dict:
+        """The reference's result ``i`` of the ``n``-th request."""
+        ws, name = self.plan.cell(i, n)
+        key = (ws.regions, ws.seed, ws.forecast, name)
+        if key not in self._results:
+            self._results[key] = self._simulate(ws, name)
+        return self._results[key]
 
-    def _simulate(self, i: int) -> dict:
-        ws, name = self.plan.cell(i)
+    def _simulate(self, ws, name: str) -> dict:
         w = self.world(ws)
+        if ws.is_geo:
+            if name not in geo.POLICIES:
+                raise ValueError(f"the reference has no geo policy {name!r}")
+            return geo.simulate_geo(w.eval_jobs, w.traces, w.capacities,
+                                    w.regions, geo.POLICIES[name](), w.t0,
+                                    WEEK, self.migration, dtype=self.dtype)
         fc = Forecast() if ws.forecast is None else Forecast(*ws.forecast)
         pol = _single_policy(name, w, ws.learn_weeks, self.dtype)
         return sim.simulate_single(w.eval_jobs, w.traces[0], fc,

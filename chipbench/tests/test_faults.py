@@ -30,7 +30,7 @@ def test_state_left_unchanged(monkeypatch, workload):
     """Each device chunk hands back the state it was given."""
     from repro.core import scan_engine
 
-    for name in ("_single_chunk", "_single_chunk_batch"):
+    for name in ("_single_chunk", "_single_chunk_batch", "_geo_chunk"):
         real = getattr(scan_engine, name)
 
         def stuck(consts, carry, xs, *a, _real=real, **kw):
@@ -61,17 +61,19 @@ def test_half_the_batch_left_out(monkeypatch, workload):
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_answer_altered_where_produced(monkeypatch, workload):
-    """Where a result is produced (the host replay), one slot's energy
-    moves by a part in a million."""
+    """Where a result is produced (the host replay of a single-region or
+    a geo case), one slot's energy moves by a part in a million."""
     from repro.core import scan_engine
 
-    real = scan_engine._account_single
+    for name in ("_account_single", "_run_geo_native"):
+        real = getattr(scan_engine, name)
 
-    def altered(*a, **kw):
-        res = real(*a, **kw)
-        s = max(res.slots, key=lambda x: x.energy_kwh)
-        s.energy_kwh = float(np.nextafter(s.energy_kwh * (1 + 1e-6), 1e300))
-        return res
+        def altered(*a, _real=real, **kw):
+            res = _real(*a, **kw)
+            s = max(res.slots, key=lambda x: x.energy_kwh)
+            s.energy_kwh = float(np.nextafter(s.energy_kwh * (1 + 1e-6),
+                                              1e300))
+            return res
 
-    monkeypatch.setattr(scan_engine, "_account_single", altered)
+        monkeypatch.setattr(scan_engine, name, altered)
     assert _run(workload)["correct"] is False
