@@ -4,8 +4,9 @@ the delegation check and the proof that a vmapped tile was dispatched).
 
 Four numbers are compared, each against its limit (``LIMITS``):
 
-- ``provision_mismatch``: fields of the jobs and CI values the program
-  materialised that differ from the benchmark's own generator;
+- ``provision_mismatch``: fields of the jobs (a DAG task's predecessors
+  among them) and CI values the program materialised that differ from
+  the benchmark's own generator;
 - ``decision_mismatch``: per-job completion slot, waiting slots,
   violation flag, per-slot servers used, jobs running and queued and
   the learned-state count that differ from
@@ -183,7 +184,8 @@ def world_mismatch(mat, world, migration=None) -> tuple[int, str]:
     """Fields of the program's materialised world (a
     ``MaterializedScenario``) that differ from the benchmark's own
     generator: every job's id, arrival, length, queue, slack, k_min,
-    power, communication size and profile, and every CI value.  A geo
+    power, communication size, profile and predecessors (the job ids of
+    a DAG task's ``deps``), and every CI value.  A geo
     world also has every region's CI trace compared, its region names,
     its capacity split, the home region of each evaluated job, and its
     migration costs against ``migration`` (a dataclass of the reference
@@ -196,7 +198,8 @@ def world_mismatch(mat, world, migration=None) -> tuple[int, str]:
                                   (a.length, b.length), (a.queue, b.queue),
                                   (a.delay, b.delay), (a.k_min, b.k_min),
                                   (a.power, b.power),
-                                  (a.comm_size, b.comm_size)) if x != y)
+                                  (a.comm_size, b.comm_size),
+                                  (tuple(a.deps), b.deps)) if x != y)
         if not np.array_equal(np.asarray(a.profile), b.profile):
             bad += 1
     bad += abs(len(mat.eval_jobs) - len(world.eval_jobs))

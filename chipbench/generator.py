@@ -17,7 +17,10 @@ A traffic mix (``traffic/<name>.json``) fixes what one request asks:
 Any traffic key named like a ``scenario`` field (``learn_weeks``, ...)
 overrides the configuration's.  A ``scenario`` with ``regions`` (two or
 more) is a geo-distributed deployment: its worlds span those regions,
-and its ``migration`` costs, when given, go to every ``Scenario``.
+and its ``migration`` costs, when given, go to every ``Scenario``.  A
+``scenario`` with ``dag`` (the fields of the program's ``DagConfig``) is
+a DAG deployment: its worlds' jobs are the tasks of whole DAGs, gated on
+their predecessors, and its band counts evaluated tasks.
 World seeds, forecast seeds and the cells the check compares all derive
 from ``--seed``; a sweep's compared cells are drawn anew for every
 request, so a window's check covers most of the grid.  A world seed is
@@ -85,6 +88,7 @@ class Plan:
     unit: str
     seed: int
     migration: dict | None = None   # MigrationModel fields of a geo world
+    dag: dict | None = None         # DagConfig fields of a DAG world
 
     @property
     def cycle(self) -> int:
@@ -122,7 +126,8 @@ def _rng(seed: int, purpose: int) -> np.random.Generator:
 
 def _world_seeds(n: int, seed: int, scen: dict, band) -> list[int]:
     """``n`` distinct world seeds from ``seed`` whose evaluated weeks hold
-    a job count inside ``band`` (inclusive); any seed when band is None."""
+    a job (DAG: task) count inside ``band`` (inclusive); any seed when
+    band is None."""
     rng = _rng(seed, 1)
     out: list[int] = []
     for _ in range(200 * n):
@@ -134,7 +139,7 @@ def _world_seeds(n: int, seed: int, scen: dict, band) -> list[int]:
                 family=scen["family"], capacity=scen["capacity"],
                 utilization=scen["utilization"],
                 learn_weeks=scen["learn_weeks"],
-                eval_weeks=scen["eval_weeks"], seed=s)
+                eval_weeks=scen["eval_weeks"], seed=s, dag=scen.get("dag"))
             if not band[0] <= c <= band[1]:
                 continue
         out.append(s)
@@ -177,8 +182,9 @@ def make_plan(config: dict, traffic: dict, seed: int) -> Plan:
         n_cmp = units
         unit = "policy-weeks"
     elif call == "sweep":
-        if geo:
-            raise ValueError("a sweep takes single-region worlds")
+        if geo or "dag" in scen:
+            raise ValueError("a sweep takes single-region worlds of "
+                             "independent jobs")
         policies = tuple(traffic["policies"])
         regions = tuple(traffic.get("sweep_regions", home))
         seeds = _world_seeds(traffic.get("seeds_per_request", 1), seed,
@@ -198,7 +204,8 @@ def make_plan(config: dict, traffic: dict, seed: int) -> Plan:
         raise ValueError(f"unknown traffic call {call!r}")
     return Plan(call=call, worlds=worlds, policies=policies,
                 units_per_request=units, n_compare=n_cmp, unit=unit,
-                seed=seed, migration=scen.get("migration"))
+                seed=seed, migration=scen.get("migration"),
+                dag=scen.get("dag"))
 
 
 class Requests:
@@ -210,12 +217,16 @@ class Requests:
         from repro.core.forecast import NoisyForecast
         from repro.core.types import MigrationModel
         from repro.experiment import Scenario, Sweep, run
+        from repro.traces import DagConfig
 
         self.plan = plan
         self._Scenario, self._Sweep, self._run = Scenario, Sweep, run
         self._Noisy = NoisyForecast
-        self._extra = {} if plan.migration is None else \
-            {"migration": MigrationModel(**plan.migration)}
+        self._extra = {}
+        if plan.migration is not None:
+            self._extra["migration"] = MigrationModel(**plan.migration)
+        if plan.dag is not None:
+            self._extra["dag"] = DagConfig(**plan.dag)
         self._sent = 0
 
     def send(self, telemetry=None) -> dict:

@@ -1,12 +1,12 @@
 """The plain reference the benchmark compares the program with: its own
 world generator (``world``) and slot-loop simulations (``sim`` for one
-region, ``geo`` for a geo-distributed cluster).  It imports nothing of
-the program."""
+region, ``geo`` for a geo-distributed cluster, ``dag`` for tasks gated
+on their predecessors).  It imports nothing of the program."""
 from __future__ import annotations
 
 import numpy as np
 
-from . import geo, sim
+from . import dag, geo, sim
 from .world import WEEK, Forecast, build_world
 
 KB_POLICIES = ("carbonflex-mpc", "carbonflex-scale")
@@ -42,7 +42,8 @@ class Reference:
         if key not in self._worlds:
             if ws.eval_weeks != 1:
                 raise ValueError("the reference evaluates one week")
-            self._worlds[key] = build_world(**ws.world_kwargs())
+            self._worlds[key] = build_world(**ws.world_kwargs(),
+                                            dag=self.plan.dag)
         return self._worlds[key]
 
     @property
@@ -66,6 +67,12 @@ class Reference:
             return geo.simulate_geo(w.eval_jobs, w.traces, w.capacities,
                                     w.regions, geo.POLICIES[name](), w.t0,
                                     WEEK, self.migration, dtype=self.dtype)
+        if self.plan.dag is not None:
+            if name not in dag.POLICIES:
+                raise ValueError(f"the reference has no DAG policy {name!r}")
+            return dag.simulate_dag(w.eval_jobs, w.traces[0], Forecast(),
+                                    w.capacities[0], dag.POLICIES[name](),
+                                    w.t0, WEEK, dtype=self.dtype)
         fc = Forecast() if ws.forecast is None else Forecast(*ws.forecast)
         pol = _single_policy(name, w, ws.learn_weeks, self.dtype)
         return sim.simulate_single(w.eval_jobs, w.traces[0], fc,

@@ -3,10 +3,10 @@
 Copied from the program (``traces/workloads.py::generate_trace``,
 ``core/profiles.py`` parametric profiles and Table 3, ``core/types.py``
 default queues, ``core/carbon.py::synthesize_trace`` and the
-``core/forecast.py`` perfect and noisy forecast models) so that the
-yardstick keeps the arithmetic it was proved with when the program
-changes.  A world here is plain data: a list of :class:`RJob` and
-per-region hourly carbon-intensity arrays.
+``core/forecast.py`` perfect and noisy forecast models; the DAG trace
+generator is ``dag.py``'s) so that the yardstick keeps the arithmetic it
+was proved with when the program changes.  A world here is plain data: a
+list of :class:`RJob` and per-region hourly carbon-intensity arrays.
 """
 from __future__ import annotations
 
@@ -72,6 +72,7 @@ class RJob:
     k_min: int
     power: float
     comm_size: float
+    deps: tuple[int, ...] = ()   # job ids of the predecessors (a DAG task)
 
     @property
     def k_max(self) -> int:
@@ -219,17 +220,29 @@ class World:
     capacities: tuple[int, ...]        # per region (one entry single-region)
 
 
+def _jobs(family: str, hours: int, capacity: int, utilization: float,
+          seed: int, dag: dict | None) -> list[RJob]:
+    """Independent jobs, or the tasks of a DAG trace when ``dag`` holds
+    the DAG generator's knobs (``dag.DagShape`` fields)."""
+    if dag is None:
+        return generate_jobs(family, hours, capacity, utilization, seed)
+    from .dag import DagShape, generate_dag_jobs
+
+    return generate_dag_jobs(family, hours, capacity, utilization, seed,
+                             DagShape(**dag))
+
+
 def build_world(*, regions: tuple[str, ...], family: str, capacity: int,
                 utilization: float, learn_weeks: int, eval_weeks: int,
-                seed: int) -> World:
+                seed: int, dag: dict | None = None) -> World:
     """The world a ``Scenario`` with these fields materialises: CI from
-    ``seed``, jobs from ``seed + 1``, capacity split evenly over the
-    regions with the remainder to the first."""
+    ``seed``, jobs (or DAG tasks) from ``seed + 1``, capacity split evenly
+    over the regions with the remainder to the first."""
     hours = WEEK * (learn_weeks + eval_weeks)
     t0 = WEEK * learn_weeks
     traces = tuple(synthesize_ci(r, hours + CI_MARGIN_HOURS, seed)
                    for r in regions)
-    jobs = generate_jobs(family, hours, capacity, utilization, seed + 1)
+    jobs = _jobs(family, hours, capacity, utilization, seed + 1, dag)
     base, rem = divmod(int(capacity), len(regions))
     caps = tuple(base + (1 if i < rem else 0) for i in range(len(regions)))
     return World(regions=tuple(regions), traces=traces, jobs=jobs,
@@ -239,9 +252,11 @@ def build_world(*, regions: tuple[str, ...], family: str, capacity: int,
 
 
 def eval_job_count(*, family: str, capacity: int, utilization: float,
-                   learn_weeks: int, eval_weeks: int, seed: int) -> int:
-    """Jobs arriving in the evaluated weeks of the world of ``seed``."""
+                   learn_weeks: int, eval_weeks: int, seed: int,
+                   dag: dict | None = None) -> int:
+    """Jobs (tasks, in a DAG trace) arriving in the evaluated weeks of the
+    world of ``seed``."""
     hours = WEEK * (learn_weeks + eval_weeks)
-    jobs = generate_jobs(family, hours, capacity, utilization, seed + 1)
+    jobs = _jobs(family, hours, capacity, utilization, seed + 1, dag)
     t0 = WEEK * learn_weeks
     return sum(1 for j in jobs if t0 <= j.arrival < hours)

@@ -1,5 +1,5 @@
 """Record ``fixtures/plans.json``, what ``test_plans_unchanged.py`` holds
-the single-region cells to: for each of them at three seeds, the plan
+the recorded cells to: for each of them at three seeds, the plan
 ``generator.make_plan`` builds (every world, the ``Scenario`` keywords
 each request sends, the compared indices of its first requests and the
 world and policy of every result), and what every per-layer reader
@@ -23,7 +23,8 @@ sys.path.insert(0, ROOT)
 
 from chipbench import generator, harness  # noqa: E402
 
-CELLS = ("single.whatif", "single.sweep-regions", "single.sweep-forecast")
+CELLS = ("single.whatif", "single.sweep-regions", "single.sweep-forecast",
+         "geo.whatif")
 SEEDS = (12345, 2 ** 31 + 5, 3000000019)
 REQUESTS = 4
 PATH = os.path.join(HERE, "fixtures", "plans.json")

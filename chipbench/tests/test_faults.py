@@ -1,7 +1,8 @@
 """A run whose timed path is broken underneath reports ``correct``
-false: once for each fault a cell of this benchmark can have.  The runs
-skip the look for a chip and run a small cell on the CPU.  No cell runs
-across chips, so there is no exchange between chips to leave out."""
+false: once for each fault a cell of this benchmark can have, and in the
+DAG cell a task released a slot early.  The runs skip the look for a
+chip and run a small cell on the CPU.  No cell runs across chips, so
+there is no exchange between chips to leave out."""
 import time
 
 import numpy as np
@@ -77,3 +78,36 @@ def test_answer_altered_where_produced(monkeypatch, workload):
 
         monkeypatch.setattr(scan_engine, name, altered)
     assert _run(workload)["correct"] is False
+
+
+def test_release_in_the_slot_its_last_predecessor_ends(monkeypatch):
+    """In the DAG cell a task whose last predecessor completes in slot t
+    is released in t, not t + 1: it may run beside its predecessor, and
+    its slack and deadline count from t."""
+    import jax
+
+    from repro.core import scan_engine
+
+    real = scan_engine._single_step
+
+    def early(consts, carry, x, *, kind, uniform, deps):
+        kw = dict(kind=kind, uniform=uniform, deps=deps)
+        if deps == "none":
+            return real(consts, carry, x, **kw)
+        now = real(consts, carry, x, **kw)[0]["pending"]
+        out, ys = real(consts, dict(carry, pending=carry["pending"] | now),
+                       x, **kw)
+        return dict(out, pending=out["pending"] & ~now), ys
+
+    def fresh(fun):
+        # a new function, so JAX traces the broken step afresh
+        def chunk(consts, carry, xs, kind, uniform, deps):
+            return fun(consts, carry, xs, kind, uniform, deps)
+
+        return jax.jit(chunk, static_argnames=("kind", "uniform", "deps"))
+
+    monkeypatch.setattr(scan_engine, "_single_step", early)
+    for name in ("_single_chunk", "_single_chunk_batch"):
+        monkeypatch.setattr(scan_engine, name,
+                            fresh(getattr(scan_engine, name).__wrapped__))
+    assert _run("dag.whatif")["correct"] is False

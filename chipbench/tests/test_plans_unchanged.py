@@ -1,4 +1,4 @@
-"""The single-region cells plan, send and read exactly as recorded in
+"""The recorded cells plan, send and read exactly as recorded in
 ``fixtures/plans.json`` (``record_plan_fixture.py``): every world of
 their plans at three seeds, the ``Scenario`` keywords each request
 sends, the results each request compares and whose they are, and what
@@ -22,10 +22,14 @@ def test_plan_unchanged(cell, seed):
     plan = generator.make_plan(config, traffic, seed)
     got = json.loads(json.dumps(rec.plan_record(plan)))
     assert got == RECORDED["plans"][cell][str(seed)]
-    assert plan.cycle == 1
+    # a run that cycles W worlds sends request n to world n mod W; every
+    # other cell's requests are alike
+    assert plan.cycle == traffic.get("worlds_per_run", 1)
     for n in range(rec.REQUESTS):
         for i in range(plan.units_per_request):
-            assert plan.cell(i, n) == plan.cell(i)
+            assert plan.cell(i, n) == (
+                plan.cell(i) if plan.cycle == 1 else
+                (plan.worlds[n % plan.cycle], plan.policies[i]))
 
 
 @pytest.mark.parametrize("name", sorted(RECORDED["readers"]))

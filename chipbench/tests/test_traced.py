@@ -15,6 +15,8 @@ from chipbench.tests.small import small_files
                                "execute_ms.sweep", "unprofiled_ms.sweep"}),
     ("geo.whatif", {"provision_ms.run", "decide_ms.run", "execute_ms.run",
                     "unprofiled_ms.run"}),
+    ("dag.whatif", {"provision_ms.run", "decide_ms.run", "execute_ms.run",
+                    "unprofiled_ms.run"}),
 ])
 def test_traced_run_reports_phase_metrics(workload, expect):
     bench, files = small_files(workload)
