@@ -72,9 +72,6 @@ class WorkloadSpec:
     scalability: str           # "high" | "moderate" | "low"
     power_kw: float = 1.0      # per-server draw (GPU cluster: heterogeneous)
 
-    def profile(self, k_min: int = 1, k_max: int = 16) -> np.ndarray:
-        return class_profile(self.scalability, k_min, k_max)
-
 
 # The paper's Table 3 workload mix (names + comm sizes + classes).  Power
 # numbers for the GPU cluster follow the paper's observation that highly

@@ -249,7 +249,9 @@ class Scenario:
         """Resolve to concrete (cluster, ci, jobs, splits); cached, so the
         same ``Scenario`` instance always yields the same job lists.
         ``profiler`` times the job-trace generation as the span
-        ``provision/jobs``."""
+        ``provision/jobs`` and counts, per generated trace, its jobs
+        (``trace_jobs``) and its distinct profile arrays
+        (``trace_profiles``)."""
         cached = self.__dict__.get("_materialized")
         if cached is not None:
             return cached
@@ -296,19 +298,24 @@ class Scenario:
             return mat
 
         def _gen(s: TraceSpec) -> list[Job]:
-            if self.dag is not None:
-                return generate_dag_trace(s, self.dag, cluster.queues)
-            return generate_trace(s, cluster.queues)
+            with span(profiler, "provision/jobs"):
+                if self.dag is not None:
+                    jobs = generate_dag_trace(s, self.dag, cluster.queues)
+                else:
+                    jobs = generate_trace(s, cluster.queues)
+            if profiler is not None:
+                profiler.count("trace_jobs", len(jobs))
+                profiler.count("trace_profiles",
+                               len({id(j.profile) for j in jobs}))
+            return jobs
 
-        with span(profiler, "provision/jobs"):
-            jobs = _gen(spec)
+        jobs = _gen(spec)
         t0 = self.t0
         # Arrival-based splits keep DAGs whole: every task of a DAG
         # arrives at the DAG's slot (gating releases it later).
         hist = [j for j in jobs if j.arrival < t0]
         if self.eval_shift:
-            with span(profiler, "provision/jobs"):
-                shifted = _gen(self.trace_spec(shifted=True))
+            shifted = _gen(self.trace_spec(shifted=True))
             eval_jobs = [j for j in shifted if t0 <= j.arrival < self.hours]
             jobs = hist + eval_jobs
         else:

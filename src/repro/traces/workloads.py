@@ -55,7 +55,7 @@ def mean_length(spec: TraceSpec) -> float:
     return max(1.0, raw)
 
 
-_TPU_PROFILE_CACHE: dict[str, np.ndarray] = {}
+_TPU_PROFILE_CACHE: dict[tuple[str, int, int], np.ndarray] = {}
 
 
 def _tpu_profile(rng: np.random.Generator, spec: TraceSpec):
@@ -68,13 +68,14 @@ def _tpu_profile(rng: np.random.Generator, spec: TraceSpec):
              "rwkv6-7b", "zamba2-7b", "musicgen-large", "dbrx-132b",
              "qwen3-moe-235b-a22b", "command-r-plus-104b"]
     name = archs[rng.integers(len(archs))]
-    if name not in _TPU_PROFILE_CACHE:
+    key = (name, spec.k_min, spec.k_max)
+    if key not in _TPU_PROFILE_CACHE:
         try:
-            _TPU_PROFILE_CACHE[name] = profile_from_dryrun(
+            _TPU_PROFILE_CACHE[key] = profile_from_dryrun(
                 name, k_min=spec.k_min, k_max=spec.k_max)
         except (FileNotFoundError, OSError):
             return None
-    prof = _TPU_PROFILE_CACHE[name]
+    prof = _TPU_PROFILE_CACHE[key]
     # comm volume per slot ~ gradient payload (GB) for Eq. 3 accounting
     from repro.configs import ARCHS
 
@@ -82,9 +83,24 @@ def _tpu_profile(rng: np.random.Generator, spec: TraceSpec):
     return prof, comm_gb, 1.0, name
 
 
-def _pick_profile(rng: np.random.Generator, spec: TraceSpec) -> tuple[np.ndarray, float, float, str]:
+def _shared_profile(memo: dict, scalability: str, spec: TraceSpec) -> np.ndarray:
+    """The parametric profile of ``scalability`` at the spec's scale, built
+    once per generator call: every job of a class shares one read-only
+    array, so an in-place write raises instead of changing the class."""
+    key = (scalability, spec.k_min, spec.k_max)
+    prof = memo.get(key)
+    if prof is None:
+        prof = (np.ones(1) if scalability == "none"
+                else class_profile(scalability, spec.k_min, spec.k_max))
+        prof.setflags(write=False)
+        memo[key] = prof
+    return prof
+
+
+def _pick_profile(rng: np.random.Generator, spec: TraceSpec, memo: dict
+                  ) -> tuple[np.ndarray, float, float, str]:
     if spec.elasticity == "none":
-        return np.ones(1), 0.0, 1.0, "rigid"
+        return _shared_profile(memo, "none", spec), 0.0, 1.0, "rigid"
     if spec.elasticity == "tpu":
         out = _tpu_profile(rng, spec)
         if out is not None:
@@ -92,10 +108,10 @@ def _pick_profile(rng: np.random.Generator, spec: TraceSpec) -> tuple[np.ndarray
         # fall through to the parametric mix when dry-run results absent
     if spec.elasticity in ("mix", "tpu"):
         w: WorkloadSpec = TABLE3_WORKLOADS[rng.integers(len(TABLE3_WORKLOADS))]
-        prof = w.profile(spec.k_min, spec.k_max)
+        prof = _shared_profile(memo, w.scalability, spec)
         power = w.power_kw if spec.mode == "gpu" else 1.0
         return prof, w.comm_size_mb / 1024.0, power, w.name
-    prof = class_profile(spec.elasticity, spec.k_min, spec.k_max)
+    prof = _shared_profile(memo, spec.elasticity, spec)
     power = {"high": 1.0, "moderate": 0.85, "low": 0.7}[spec.elasticity] \
         if spec.mode == "gpu" else 1.0
     return prof, 0.05, power, spec.elasticity
@@ -160,6 +176,7 @@ def generate_dag_specs(spec: TraceSpec, dag: DagConfig) -> list["DagSpec"]:
                                 map_reduce_tasks)
 
     rng = np.random.default_rng(spec.seed)
+    profiles: dict = {}
     _, _, diurnal = TRACE_FAMILIES[spec.family]
     mean_task = dag_mean_task_length(dag, spec.length_scale)
     base_rate = (spec.utilization * spec.capacity
@@ -193,7 +210,7 @@ def generate_dag_specs(spec: TraceSpec, dag: DagConfig) -> list["DagSpec"]:
                 tasks = layered_tasks(sizes, _len(sum(sizes)), rng,
                                       max_parents=dag.max_parents)
             for task in tasks:          # Table-3 elasticity per task
-                prof, comm, power, _ = _pick_profile(rng, spec)
+                prof, comm, power, _ = _pick_profile(rng, spec, profiles)
                 task.profile = prof
                 task.comm_size = comm
                 task.power = power
@@ -221,6 +238,7 @@ def generate_trace(spec: TraceSpec, queues: tuple[QueueConfig, ...] | None = Non
     if queues is None:
         queues = ClusterConfig.default(spec.capacity).queues
     rng = np.random.default_rng(spec.seed)
+    profiles: dict = {}
     mu, sigma, diurnal = TRACE_FAMILIES[spec.family]
     mean_len = mean_length(spec)
     # expected demand per slot = rate * mean_len * k_min = util * M
@@ -240,7 +258,7 @@ def generate_trace(spec: TraceSpec, queues: tuple[QueueConfig, ...] | None = Non
             length = float(np.exp(rng.normal(mu, sigma))) * spec.length_scale
             length = float(np.clip(length, 1.0, 24 * 4))    # hour+ jobs
             qidx = next(i for i, q in enumerate(queues) if length <= q.max_length)
-            prof, comm, power, name = _pick_profile(rng, spec)
+            prof, comm, power, name = _pick_profile(rng, spec, profiles)
             jobs.append(Job(
                 job_id=jid,
                 arrival=t,

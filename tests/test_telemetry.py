@@ -432,7 +432,7 @@ SCAN_SPANS = {"pack", "policy_tables", "build", "provision/jobs",
               "decide/wait", "decide/fetch"}
 SCAN_COUNTERS = {"scan_slots", "scan_slots_past_end", "h2d_bytes",
                  "d2h_bytes", "pack_builds", "pack_jobs",
-                 "pack_profile_tables"}
+                 "pack_profile_tables", "trace_jobs", "trace_profiles"}
 SCAN_POLICIES = ["carbon-agnostic", "wait-awhile", "carbonflex-mpc"]
 
 
