@@ -9,10 +9,8 @@ results/bench/.
 """
 from __future__ import annotations
 
-import datetime
 import json
 import os
-import subprocess
 import time
 
 from repro.experiment import Scenario, run as run_experiment
@@ -20,26 +18,7 @@ from repro.experiment import Scenario, run as run_experiment
 WEEK = 24 * 7
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results", "bench")
 
-__all__ = ["Scenario", "run_policies", "cached", "csv_rows", "WEEK",
-           "bench_metadata"]
-
-
-def bench_metadata() -> dict:
-    """Provenance stamp for committed BENCH json payloads: the git SHA the
-    numbers were measured at plus a UTC timestamp.  ``"unknown"`` outside
-    a git checkout so benches still run from tarballs."""
-    try:
-        sha = subprocess.run(
-            ["git", "rev-parse", "HEAD"],
-            cwd=os.path.dirname(os.path.abspath(__file__)),
-            capture_output=True, text=True, timeout=10).stdout.strip()
-    except (OSError, subprocess.SubprocessError):
-        sha = ""
-    return {
-        "git_sha": sha or "unknown",
-        "timestamp": datetime.datetime.now(datetime.timezone.utc)
-        .strftime("%Y-%m-%dT%H:%M:%SZ"),
-    }
+__all__ = ["Scenario", "run_policies", "cached", "csv_rows", "WEEK"]
 
 
 def run_policies(sc: Scenario, policies: list[str] | None = None) -> dict:

@@ -1,14 +1,10 @@
 """Benchmark entry point: ``PYTHONPATH=src python -m benchmarks.run``.
 
-One benchmark per paper table/figure (see figures.ALL) + the roofline
-report.  Prints ``name,us_per_call,derived`` CSV.  Results are cached in
-results/bench/ — pass ``--force`` to recompute, ``--only fig6`` to filter.
-
-``--engine`` runs the batch-engine micro-benchmark (BENCH_engine.json),
-``--serve`` the serving-engine benchmark (BENCH_serve.json); the two
-combine, and either replaces the figure suite.  Every section runs behind
-its own failure guard — a crashing section is reported and the rest still
-run; the process exits non-zero at the end if anything failed.
+One benchmark per paper table/figure (see figures.ALL).  Prints
+``name,us_per_call,derived`` CSV.  Results are cached in results/bench/ —
+pass ``--force`` to recompute, ``--only fig6`` to filter.  A crashing
+figure is reported and the rest still run; the process exits non-zero at
+the end if any failed.  The chip benchmark is ``chipbench/run.py``.
 """
 from __future__ import annotations
 
@@ -21,55 +17,23 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--force", action="store_true")
-    ap.add_argument("--skip-roofline", action="store_true")
-    ap.add_argument("--engine", action="store_true",
-                    help="run the batch-engine micro-benchmark "
-                         "(BENCH_engine.json) instead of the figure suite")
-    ap.add_argument("--serve", action="store_true",
-                    help="run the serving-engine benchmark "
-                         "(BENCH_serve.json) instead of the figure suite; "
-                         "combines with --engine")
-    ap.add_argument("--smoke", action="store_true",
-                    help="shrink --engine/--serve to a CI smoke and skip "
-                         "the BENCH_*.json writes")
     args = ap.parse_args()
 
     from repro.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    failures: list[str] = []
-
-    def section(name: str, fn) -> None:
-        try:
-            fn()
-        except Exception as e:  # noqa: BLE001
-            print(f"{name},0,ERROR={e!r}", file=sys.stderr)
-            failures.append(name)
-
-    if args.engine or args.serve:
-        print("name,us_per_call,derived")
-        if args.engine:
-            from . import bench_engine
-
-            section("bench_engine",
-                    lambda: bench_engine.run_and_report(smoke=args.smoke))
-        if args.serve:
-            from . import bench_serve
-
-            section("bench_serve",
-                    lambda: bench_serve.run_and_report(smoke=args.smoke))
-    else:
-        _figure_suite(args, failures, section)
-
+    failures = _figure_suite(args)
     if failures:
-        print(f"FAILED sections: {', '.join(failures)}", file=sys.stderr)
+        print(f"FAILED figures: {', '.join(failures)}", file=sys.stderr)
         sys.exit(1)
 
 
-def _figure_suite(args, failures: list[str], section) -> None:
-    from . import figures, roofline
+def _figure_suite(args) -> list[str]:
+    """Run the figures and print their CSV rows; returns those that failed."""
+    from . import figures
     from .common import RESULTS_DIR, cached, csv_rows
 
+    failures: list[str] = []
     print("name,us_per_call,derived")
     for name, fn in figures.ALL.items():
         if args.only and args.only not in name:
@@ -136,8 +100,7 @@ def _figure_suite(args, failures: list[str], section) -> None:
             continue
         for row in csv_rows(name, res):
             print(row)
-    if not args.skip_roofline and not args.only:
-        section("roofline", lambda: [print(r) for r in roofline.csv_rows()])
+    return failures
 
 
 if __name__ == "__main__":

@@ -2,7 +2,7 @@
 
 Drives the main path, ``Scenario(engine="scan")`` -> ``run()``/``Sweep`` ->
 the jitted ``lax.scan`` slot loop on the device, at the paper's scale
-(capacity 150, the ``bench_engine --full`` scale) and holds every phase
+(capacity 150) and holds every phase
 to the engines' bit-parity contract:
 
 - single-region: the scan-native policies through ``run()``, bit-equal to

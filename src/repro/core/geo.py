@@ -98,8 +98,8 @@ class GeoGreedyPolicy:
     Greedy means myopic, not immobile: every decision — placement and
     migration alike — reads only the current CI vector, never the
     forecast (that is geo-flex's edge).  The original sticky-placement
-    variant reported ``migrations: 0`` in BENCH_engine.json §geo not
-    because migration was never profitable (geo-flex found 171 moves on
+    variant reported zero migrations in a geo-greedy week (a CPU
+    benchmark since removed) not because migration was never profitable (geo-flex found 171 moves on
     the same trace) but because the policy had no migration rule at all;
     the myopic rule below closes that gap while preserving the
     greedy/flex contrast, and is pinned by a two-region large-CI-gap

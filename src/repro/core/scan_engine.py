@@ -1,10 +1,8 @@
 """The scan engine: the per-slot simulation loop as a jitted ``lax.scan``.
 
-``BENCH_engine.json`` showed the numpy vector engine's wins collapsing
-exactly where the interesting policies live (geo-flex 1.1x, dag-carbon
-1.4x vs 3.9-4.1x for simple policies): every slot still round-trips
-through Python for the policy decision and the defensive trimming.  This
-module lifts the whole slot loop onto the device:
+The numpy vector engine round-trips through Python every slot for the
+policy decision and the defensive trimming.  This module lifts the whole
+slot loop onto the device:
 
 - the *decision* of every nativizable policy is expressed as packed
   array ops inside the scan step (threshold-fill for the single-region
@@ -12,9 +10,11 @@ module lifts the whole slot loop onto the device:
 - admission, dependency gating (pred-count decrement via
   ``kernels/gating.py``), release and deadline-from-release live in the
   carried state;
-- whole (seeds x policies x regions x forecasts) grids run as one
-  vmapped device program (`simulate_many_scan`), chunked so termination
-  is checked on the host between chunks.
+- structurally identical cases of a (seeds x policies x regions x
+  forecasts) grid run as one vmapped device program; every case, one or
+  many, enters through `simulate_many_scan`, and every device program
+  runs through one chunk loop (`_collect_chunks`) that checks
+  termination on the host between chunks.
 
 Bit-parity contract
 -------------------
@@ -1089,70 +1089,60 @@ def _nbytes(tree) -> int:
     return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
 
 
-def _collect_chunks(prog_consts, carry, chunk_fn, xs_fn, t0: int,
-                    t_mid: int, t_hard: int,
-                    prof: PhaseProfiler | None = None) -> tuple[dict, int]:
-    """Run device chunks until the case ends or t_hard; returns stacked
-    host ys + the count of valid (pre-termination) slots.
+def _collect_chunks(consts, carry, chunk_fn, xs_at, horizon: int,
+                    t_span: int, prof: PhaseProfiler | None = None,
+                    axis: int = 0) -> tuple[dict, int, int, int]:
+    """Run device chunks from slot offset 0 until every case has ended or
+    ``t_span`` slots have run.
 
-    Inside the horizon (< ``t_mid``) termination is impossible (the
-    engines' ended-check requires ``t >= t0 + horizon``), so full CHUNK
-    dispatches are free of waste; past the horizon the case can end any
+    ``xs_at(off, size)`` gives the host tables of slots ``off`` to
+    ``off + size`` (offsets from each case's ``t0``).  Inside the horizon
+    (``off < horizon``) termination is impossible (the engines'
+    ended-check requires ``t >= t0 + horizon``), so full CHUNK
+    dispatches are free of waste; past the horizon a case can end any
     slot, so smaller OVERRUN_CHUNK dispatches bound the slots computed
     beyond the actual end.  With a profiler attached, each chunk's host
-    tables, upload, device wait and fetch are spans of ``decide``, and
-    the transfers and computed slots are counted."""
+    tables, upload, device wait and fetch are spans of ``decide``.
+    Returns the host ys joined along the slot ``axis`` (1 for a vmapped
+    batch), the slots run, and the bytes sent and fetched."""
     ys_parts = []
-    t_lo = t0
-    while t_lo < t_hard:
-        cap = CHUNK if t_lo < t_mid else OVERRUN_CHUNK
-        size = min(cap, t_hard - t_lo)
+    off = h2d = d2h = 0
+    while off < t_span:
+        size = min(CHUNK if off < horizon else OVERRUN_CHUNK, t_span - off)
         with span(prof, "decide/tables"):
-            xs = xs_fn(np.arange(t_lo, t_lo + size))
+            xs = xs_at(off, size)
         with span(prof, "decide/upload"):
             xs = jax.device_put(xs)
         with span(prof, "decide/wait"):
-            carry, ys = chunk_fn(prog_consts, carry, xs)
+            carry, ys = chunk_fn(consts, carry, xs)
             if prof is not None:
                 jax.block_until_ready(ys)
         with span(prof, "decide/fetch"):
             ys = jax.device_get(ys)
-            ended = bool(np.asarray(carry["ended"]))
-        if prof is not None:
-            prof.count("h2d_bytes", _nbytes(xs))
-            prof.count("d2h_bytes", _nbytes(ys) + carry["ended"].nbytes)
+            ended = bool(np.asarray(carry["ended"]).all())
+        h2d += _nbytes(xs)
+        d2h += _nbytes(ys) + carry["ended"].nbytes
         ys_parts.append(ys)
-        t_lo += size
+        off += size
         if ended:
             break
-    ys = {k: np.concatenate([p[k] for p in ys_parts]) for k in ys_parts[0]}
+    ys = {k: np.concatenate([p[k] for p in ys_parts], axis=axis)
+          for k in ys_parts[0]}
+    return ys, off, h2d, d2h
+
+
+def _count_chunks(prof: PhaseProfiler | None, ys: dict, slots: int,
+                  h2d: int, d2h: int) -> int:
+    """One case's valid (pre-termination) slot count from its ys, with
+    the slots computed and the bytes moved counted on ``prof``."""
     ended = np.asarray(ys["ended"], dtype=bool)
     n_valid = int(np.argmax(ended)) if ended.any() else len(ended)
     if prof is not None:
-        prof.count("scan_slots", len(ended))
-        prof.count("scan_slots_past_end", len(ended) - n_valid)
-    return ys, n_valid
-
-
-def _run_single_native(packed, ci, ci_pol, cluster, policy, t0, horizon,
-                       max_overrun, kind,
-                       telemetry: Telemetry | None = None) -> SimResult:
-    prof = telemetry.profiler if telemetry is not None else None
-    with span(prof, "build"):
-        prog = _build_single(packed, cluster, policy, ci_pol, kind, t0,
-                             horizon, prof)
-    t_hard = t0 + horizon + max_overrun
-
-    def chunk_fn(consts, carry, xs):
-        return _single_chunk(consts, carry, xs, prog.kind, prog.uniform,
-                             prog.deps)
-
-    with span(prof, "decide"):
-        ys, n_valid = _collect_chunks(prog.consts, prog.carry0, chunk_fn,
-                                      prog.xs_fn, t0, t0 + horizon, t_hard,
-                                      prof)
-    return _account_single(packed, ci, ci_pol, cluster, policy, t0, ys,
-                           n_valid, prog, telemetry=telemetry)
+        prof.count("scan_slots", slots)
+        prof.count("scan_slots_past_end", slots - n_valid)
+        prof.count("h2d_bytes", h2d)
+        prof.count("d2h_bytes", d2h)
+    return n_valid
 
 
 def _scan_admit_slots(packed, t0, n_valid, fs, fr):
@@ -1301,7 +1291,6 @@ def _run_geo_native(packed, mci, ci_pol, geo, policy, t0, horizon,
 
     tele, prof, _, _ = _telemetry_hooks(telemetry, None)
     lookahead = int(getattr(policy, "lookahead", 24))
-    t_hard = t0 + horizon + max_overrun
     with span(prof, "build"):
         prog = _build_geo(packed, geo, policy, ci_pol, t0, horizon, kind,
                           prof)
@@ -1309,10 +1298,14 @@ def _run_geo_native(packed, mci, ci_pol, geo, policy, t0, horizon,
     def chunk_fn(consts, carry, xs):
         return _geo_chunk(consts, carry, xs, kind, lookahead, prog.uniform)
 
+    def xs_at(off, size):
+        return prog.xs_fn(np.arange(t0 + off, t0 + off + size))
+
     with span(prof, "decide"):
-        ys, n_valid = _collect_chunks(prog.consts, prog.carry0, chunk_fn,
-                                      prog.xs_fn, t0, t0 + horizon, t_hard,
-                                      prof)
+        ys, slots, h2d, d2h = _collect_chunks(
+            prog.consts, prog.carry0, chunk_fn, xs_at, horizon,
+            horizon + max_overrun, prof)
+    n_valid = _count_chunks(prof, ys, slots, h2d, d2h)
 
     n = packed.n
     n_regions = geo.n_regions
@@ -1420,61 +1413,17 @@ def _run_geo_native(packed, mci, ci_pol, geo, policy, t0, horizon,
 # --- public API --------------------------------------------------------------
 
 
-def simulate_scan(jobs, ci, cluster, policy, t0: int = 0,
-                  horizon: int | None = None, max_overrun: int = 24 * 21,
-                  faults=None, packed=None,
-                  telemetry: Telemetry | None = None) -> SimResult:
-    """``simulate(..., engine="scan")``: jitted lax.scan slot loop for
-    native policies, transparent vector-engine delegation otherwise."""
-    from .simulator import (_packed_for, _policy_ci_view, _simulate_vector,
-                            _simulate_geo_vector)
-
-    prof = telemetry.profiler if telemetry is not None else None
-    if packed is None:
-        with span(prof, "pack"):
-            packed = _packed_for(jobs, prof)
-    kind = native_kind(policy, cluster, faults)
-    if (kind == "mpc-scale" and telemetry is not None
-            and telemetry.recorder is not None):
-        # _scan_slot_events derives resume/suspend assuming k == k_min
-        # (no scale events); event-recorded scale runs use the vector
-        # engine, whose tracker sees the true per-slot allocations
-        kind = None
-    if kind is None or packed.n == 0 or (packed.has_deps
-                                         and isinstance(cluster, GeoCluster)):
-        if isinstance(cluster, GeoCluster):
-            # geo + deps delegates so the vector engine raises its usual
-            # "geo engines do not support DAG jobs" rejection
-            return _simulate_geo_vector(jobs, ci, cluster, policy, t0,
-                                        horizon, max_overrun, faults,
-                                        packed=packed, telemetry=telemetry)
-        return _simulate_vector(jobs, ci, cluster, policy, t0, horizon,
-                                max_overrun, faults, packed=packed,
-                                telemetry=telemetry)
-    horizon = int(horizon if horizon is not None else len(ci) - t0)
-    ci_pol = _policy_ci_view(ci)
-    with span(prof, "policy_tables"):
-        policy.on_window_start(ci_pol, t0, horizon, packed.jobs, cluster)
-    with jax.enable_x64(True):
-        if kind in _SINGLE_KINDS:
-            return _run_single_native(packed, ci, ci_pol, cluster, policy,
-                                      t0, horizon, max_overrun, kind,
-                                      telemetry=telemetry)
-        return _run_geo_native(packed, ci, ci_pol, cluster, policy, t0,
-                               horizon, max_overrun, kind,
-                               telemetry=telemetry)
-
-
 def simulate_many_scan(cases: Sequence) -> list[SimResult]:
-    """Batch path: group scan-native single-region cases by structure and
-    run each group as one vmapped device program (chunked); geo-native
+    """The ``engine="scan"`` path, for one case or a sweep: group
+    scan-native single-region cases by structure and run each group in
+    tiles (one vmapped device program per tile, chunked); geo-native
     cases run per-case through the jitted geo scan; everything else
     delegates to the vector engine."""
     from .simulator import (_packed_for, _policy_ci_view, _simulate_vector,
                             _simulate_geo_vector)
 
     results: list[SimResult | None] = [None] * len(cases)
-    groups: dict[tuple, list[tuple[int, object, object, _SingleProgram]]] = {}
+    groups: dict[tuple, list[tuple]] = {}
     delegated: dict[str, int] = {}
     with jax.enable_x64(True):
         for i, case in enumerate(cases):
@@ -1485,9 +1434,15 @@ def simulate_many_scan(cases: Sequence) -> list[SimResult]:
             kind = native_kind(case.policy, case.cluster, case.faults)
             if (kind == "mpc-scale" and telemetry is not None
                     and telemetry.recorder is not None):
-                kind = None     # see simulate_scan: scale events
+                # _scan_slot_events derives resume/suspend assuming
+                # k == k_min (no scale events); event-recorded scale runs
+                # use the vector engine, whose tracker sees the true
+                # per-slot allocations
+                kind = None
             if kind is None or packed.n == 0 or (
                     packed.has_deps and isinstance(case.cluster, GeoCluster)):
+                # geo + deps delegates so the vector engine raises its
+                # usual "geo engines do not support DAG jobs" rejection
                 if packed.n > 0:
                     who = (getattr(case, "label", "")
                            or type(case.policy).__name__)
@@ -1523,7 +1478,8 @@ def simulate_many_scan(cases: Sequence) -> list[SimResult]:
             key = (prog.n_pad, prog.kind, prog.xs_dims, prog.deps,
                    int(dep_dim), prog.uniform, horizon,
                    horizon + case.max_overrun)
-            groups.setdefault(key, []).append((i, case, packed, prog, ci_pol))
+            groups.setdefault(key, []).append(
+                (i, case, packed, prog, ci_pol, horizon))
         for key, members in groups.items():
             for lo in range(0, len(members), BATCH_TILE):
                 _run_single_tile(members[lo:lo + BATCH_TILE], results)
@@ -1537,95 +1493,57 @@ def simulate_many_scan(cases: Sequence) -> list[SimResult]:
 
 
 def _run_single_tile(members, results) -> None:
-    """One vmapped tile of structurally identical single-region cases."""
-    if len(members) == 1:
-        i, case, packed, prog, ci_pol = members[0]
-        horizon = int(case.horizon if case.horizon is not None
-                      else len(case.ci) - case.t0)
-        t_hard = case.t0 + horizon + case.max_overrun
-
-        def chunk_fn(consts, carry, xs):
-            return _single_chunk(consts, carry, xs, prog.kind, prog.uniform,
-                                 prog.deps)
-
-        telemetry = getattr(case, "telemetry", None)
-        prof = telemetry.profiler if telemetry is not None else None
-        with span(prof, "decide"):
-            ys, n_valid = _collect_chunks(prog.consts, prog.carry0, chunk_fn,
-                                          prog.xs_fn, case.t0,
-                                          case.t0 + horizon, t_hard, prof)
-        results[i] = _account_single(packed, case.ci, ci_pol, case.cluster,
-                                     case.policy, case.t0, ys, n_valid, prog,
-                                     telemetry=telemetry)
-        return
-
+    """One tile of structurally identical single-region cases: a lone
+    case runs ``_single_chunk``, more run vmapped in
+    ``_single_chunk_batch``."""
     tels = [getattr(m[1], "telemetry", None) for m in members]
     profs = [t.profiler if t is not None else None for t in tels]
     # a sweep's cells share one profiler: the tile is then one bracket of
     # it, with the spans of each chunk; otherwise no spans, and the
     # members split the tile's decide time evenly
     prof = profs[0] if all(p is profs[0] for p in profs) else None
-    watched = any(p is not None for p in profs)
-    kind_b = members[0][3].kind
-    uniform = members[0][3].uniform
-    deps = members[0][3].deps
-    with span(prof, "build"):
-        consts = {k: jnp.stack([m[3].consts[k] for m in members])
-                  for k in members[0][3].consts}
-        carry = {k: jnp.stack([m[3].carry0[k] for m in members])
-                 for k in members[0][3].carry0}
-    horizon_b = int(members[0][1].horizon
-                    if members[0][1].horizon is not None
-                    else len(members[0][1].ci) - members[0][1].t0)
-    t_span = members[0][1].max_overrun + horizon_b
-    ys_parts = []
-    off = h2d = d2h = 0
+    _, case0, _, prog0, _, horizon = members[0]
+    batched = len(members) > 1
+    if batched:
+        with span(prof, "build"):
+            consts = {k: jnp.stack([m[3].consts[k] for m in members])
+                      for k in prog0.consts}
+            carry = {k: jnp.stack([m[3].carry0[k] for m in members])
+                     for k in prog0.carry0}
+        chunk = _single_chunk_batch
+
+        def xs_at(off, size):
+            xs_host = [m[3].xs_fn(np.arange(m[1].t0 + off,
+                                            m[1].t0 + off + size))
+                       for m in members]
+            return {k: np.stack([d[k] for d in xs_host]) for k in xs_host[0]}
+    else:
+        consts, carry = prog0.consts, prog0.carry0
+        chunk = _single_chunk
+
+        def xs_at(off, size):
+            return prog0.xs_fn(np.arange(case0.t0 + off,
+                                         case0.t0 + off + size))
+
+    def chunk_fn(consts, carry, xs):
+        return chunk(consts, carry, xs, prog0.kind, prog0.uniform, prog0.deps)
+
     _dev_t0 = time.perf_counter()
     with span(prof, "decide"):
-        while off < t_span:
-            size = min(CHUNK if off < horizon_b else OVERRUN_CHUNK,
-                       t_span - off)
-            with span(prof, "decide/tables"):
-                xs_host = [m[3].xs_fn(np.arange(m[1].t0 + off,
-                                                m[1].t0 + off + size))
-                           for m in members]
-                xs = {k: np.stack([d[k] for d in xs_host])
-                      for k in xs_host[0]}
-            with span(prof, "decide/upload"):
-                xs = {k: jnp.asarray(v) for k, v in xs.items()}
-            with span(prof, "decide/wait"):
-                carry, ys = _single_chunk_batch(consts, carry, xs, kind_b,
-                                                uniform, deps)
-                if prof is not None:
-                    jax.block_until_ready(ys)
-            with span(prof, "decide/fetch"):
-                ys = jax.device_get(ys)
-                ended = bool(np.asarray(carry["ended"]).all())
-            if watched:
-                h2d += _nbytes(xs)
-                d2h += _nbytes(ys) + carry["ended"].nbytes
-            ys_parts.append(ys)
-            off += size
-            if ended:
-                break
+        ys_all, slots, h2d, d2h = _collect_chunks(
+            consts, carry, chunk_fn, xs_at, horizon,
+            horizon + case0.max_overrun, prof, axis=1 if batched else 0)
     if prof is None:
         _dev_dt = (time.perf_counter() - _dev_t0) / len(members)
         for p in profs:
             if p is not None:
                 p.add("decide", _dev_dt)
-    ys_all = {k: np.concatenate([p[k] for p in ys_parts], axis=1)
-              for k in ys_parts[0]}
-    for j, (i, case, packed, prog, ci_pol) in enumerate(members):
-        ys = {k: v[j] for k, v in ys_all.items()}
-        ended = np.asarray(ys["ended"], dtype=bool)
-        n_valid = int(np.argmax(ended)) if ended.any() else len(ended)
-        if profs[j] is not None:
-            # every member computed every slot the tile ran; the stacked
-            # transfers divide evenly (members share their shapes)
-            profs[j].count("scan_slots", off)
-            profs[j].count("scan_slots_past_end", off - n_valid)
-            profs[j].count("h2d_bytes", h2d // len(members))
-            profs[j].count("d2h_bytes", d2h // len(members))
+    for j, (i, case, packed, prog, ci_pol, _) in enumerate(members):
+        ys = {k: v[j] for k, v in ys_all.items()} if batched else ys_all
+        # every member computed every slot the tile ran; the stacked
+        # transfers divide evenly (members share their shapes)
+        n_valid = _count_chunks(profs[j], ys, slots, h2d // len(members),
+                                d2h // len(members))
         results[i] = _account_single(packed, case.ci, ci_pol, case.cluster,
                                      case.policy, case.t0, ys, n_valid, prog,
                                      telemetry=tels[j])

@@ -34,8 +34,10 @@ Two engines, bit-for-bit identical outputs (tests/test_engine_parity.py):
 - ``engine="scalar"`` — the readable per-ActiveJob reference
   implementation, kept as the parity oracle.
 
-``simulate_many`` batches a (seeds x regions x policies) sweep through
-the vector engine, packing each distinct job list once.
+``simulate_many`` runs a (seeds x regions x policies) sweep, packing
+each distinct job list once, and is the one place that picks a case's
+engine; ``simulate`` is its one-case form.  ``engine="scan"`` is the
+jitted device loop of ``core/scan_engine.py``.
 """
 from __future__ import annotations
 
@@ -318,24 +320,10 @@ def simulate(
     engine: str = "vector",
     telemetry: Telemetry | None = None,
 ) -> SimResult:
-    if engine not in ("vector", "scalar", "scan"):
-        raise ValueError(f"unknown engine {engine!r}")
-    if isinstance(cluster, GeoCluster) and not isinstance(
-            ci, MultiRegionCarbonService):
-        raise TypeError("a GeoCluster needs a MultiRegionCarbonService")
-    if engine == "scan":
-        from .scan_engine import simulate_scan
-        return simulate_scan(jobs, ci, cluster, policy, t0, horizon,
-                             max_overrun, faults, telemetry=telemetry)
-    if isinstance(cluster, GeoCluster):
-        fn = _simulate_geo_scalar if engine == "scalar" else _simulate_geo_vector
-        return fn(jobs, ci, cluster, policy, t0, horizon, max_overrun, faults,
-                  telemetry=telemetry)
-    if engine == "scalar":
-        return _simulate_scalar(jobs, ci, cluster, policy, t0, horizon,
-                                max_overrun, faults, telemetry=telemetry)
-    return _simulate_vector(jobs, ci, cluster, policy, t0, horizon,
-                            max_overrun, faults, telemetry=telemetry)
+    """One run: ``simulate_many`` of a single case."""
+    return simulate_many([SimCase(jobs, ci, cluster, policy, t0, horizon,
+                                  max_overrun, faults, engine=engine,
+                                  telemetry=telemetry)])[0]
 
 
 # --- vector engine ----------------------------------------------------------
@@ -597,40 +585,45 @@ class SimCase:
 
 
 def simulate_many(cases: Iterable[SimCase] | Sequence[SimCase]) -> list[SimResult]:
-    """Run a (seeds x regions x policies) sweep through the batch engines.
+    """Run a (seeds x regions x policies) sweep; the one place that picks
+    each case's engine.
 
     Each distinct ``jobs`` list is packed into its struct-of-arrays form
     exactly once (sorting, throughput/marginal tables, scheduling entry
     blocks), so per-configuration cost is the slot loop itself rather
     than per-configuration re-setup — the batch path for the paper's
-    Fig. 6–14 sweeps at ``--full`` scale.  Cases whose ``cluster`` is a
-    :class:`GeoCluster` dispatch to the multi-region engine; cases with
-    ``engine="scan"`` run through the jitted lax.scan path, and
-    structurally identical scan cases fuse into one vmapped device
-    program (``scan_engine.simulate_many_scan``)."""
+    Fig. 6–14 sweeps at ``--full`` scale.  Each case runs on its
+    ``engine``: ``"vector"``, ``"scalar"`` (the reference loop) or
+    ``"scan"``, where structurally identical cases fuse into one vmapped
+    device program (``scan_engine.simulate_many_scan``).  Cases whose
+    ``cluster`` is a :class:`GeoCluster` run that engine's multi-region
+    form."""
     cases = list(cases)
-    scan_idx = [i for i, c in enumerate(cases)
-                if getattr(c, "engine", "vector") == "scan"]
+    engines = [getattr(c, "engine", "vector") for c in cases]
+    for case, engine in zip(cases, engines):
+        if engine not in ("vector", "scalar", "scan"):
+            raise ValueError(f"unknown engine {engine!r}")
+        if isinstance(case.cluster, GeoCluster) and not isinstance(
+                case.ci, MultiRegionCarbonService):
+            raise TypeError("a GeoCluster needs a MultiRegionCarbonService")
     out: list[SimResult | None] = [None] * len(cases)
+    scan_idx = [i for i, e in enumerate(engines) if e == "scan"]
     if scan_idx:
         from .scan_engine import simulate_many_scan
         for i, res in zip(scan_idx,
                           simulate_many_scan([cases[i] for i in scan_idx])):
             out[i] = res
-    for i, case in enumerate(cases):
-        if out[i] is not None:
+    for i, (case, engine) in enumerate(zip(cases, engines)):
+        if engine == "scan":
             continue
-        telemetry = getattr(case, "telemetry", None)
-        if isinstance(case.cluster, GeoCluster):
-            out[i] = _simulate_geo_vector(
-                case.jobs, case.ci, case.cluster, case.policy, case.t0,
-                case.horizon, case.max_overrun, case.faults,
-                packed=_packed_for(case.jobs), telemetry=telemetry)
+        geo = isinstance(case.cluster, GeoCluster)
+        if engine == "scalar":
+            fn = _simulate_geo_scalar if geo else _simulate_scalar
         else:
-            out[i] = _simulate_vector(
-                case.jobs, case.ci, case.cluster, case.policy, case.t0,
-                case.horizon, case.max_overrun, case.faults,
-                packed=_packed_for(case.jobs), telemetry=telemetry)
+            fn = _simulate_geo_vector if geo else _simulate_vector
+        out[i] = fn(case.jobs, case.ci, case.cluster, case.policy, case.t0,
+                    case.horizon, case.max_overrun, case.faults,
+                    telemetry=getattr(case, "telemetry", None))
     return out
 
 

@@ -14,8 +14,9 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --multi-pod        # 2x16x16
   PYTHONPATH=src python -m repro.launch.dryrun --out results/dryrun
 
-Outputs one JSON per cell under --out (consumed by benchmarks/roofline.py
-and by core/profiles.py for CarbonFlex scaling profiles).
+Outputs one JSON per cell under --out, with a ``roofline`` block of the
+three terms (consumed by core/profiles.py for CarbonFlex scaling
+profiles).
 """
 import argparse
 import dataclasses

@@ -33,6 +33,12 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import sys
+
+# Bound on the summed magnitude of the CI-priced causes: far above any
+# real run's grams, and low enough that the residual and every partial
+# sum of the fit stay finite.
+_PRICED_MAX = sys.float_info.max / 16
 
 CAUSES = ("temporal_shifting", "capacity_scaling", "geo_placement",
           "migration_overhead", "precision_tiering", "fault_restore")
@@ -156,7 +162,15 @@ def attribute(result, baseline) -> Attribution:
     delta = float(baseline.carbon_g - result.carbon_g)
     ci_ref = (baseline.carbon_g / baseline.energy_kwh
               if baseline.energy_kwh > 0 else 0.0)
-    e_delta = (baseline.energy_kwh - result.energy_kwh) * ci_ref
+    de = baseline.energy_kwh - result.energy_kwh
+    dr = _restore_energy(baseline) - _restore_energy(result)
+    if not abs(de * ci_ref) + abs(dr * ci_ref) <= _PRICED_MAX:
+        # a baseline energy near zero (or subnormal) prices the energy
+        # deltas past what the residual fit can sum: cap the price so
+        # the priced causes stay summable (real runs never reach this)
+        moved = abs(de) + abs(dr)
+        ci_ref = min(ci_ref, _PRICED_MAX / moved) if moved else 0.0
+    e_delta = de * ci_ref
     serving = result.serving is not None or baseline.serving is not None
     # float() coercions: slot logs and migration totals may be numpy
     # scalars, and the causes dict is the public surface (repr'd into
@@ -169,8 +183,7 @@ def attribute(result, baseline) -> Attribution:
         "migration_overhead": float(baseline.migration_carbon_g
                                     - result.migration_carbon_g),
         "precision_tiering": float(e_delta) if serving else 0.0,
-        "fault_restore": float((_restore_energy(baseline)
-                                - _restore_energy(result)) * ci_ref),
+        "fault_restore": float(dr * ci_ref),
     }
     fitted = _fit_residual(causes, delta)
     for _ in range(8):

@@ -39,9 +39,14 @@ def _install_hypothesis_shim() -> None:
 
         return deco
 
+    def example(*_args, **_kwargs):
+        # a fixed example of a test the shim skips anyway
+        return lambda fn: fn
+
     hyp = types.ModuleType("hypothesis")
     hyp.given = given
     hyp.settings = settings
+    hyp.example = example
     hyp.strategies = st
     sys.modules["hypothesis"] = hyp
     sys.modules["hypothesis.strategies"] = st

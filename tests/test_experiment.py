@@ -172,6 +172,34 @@ class TestBatchParity:
         np.testing.assert_array_equal(solo.violations, r.violations)
 
 
+@pytest.mark.parametrize("engine", ["scalar", "vector", "scan"])
+def test_simulate_many_honours_engine(monkeypatch, engine):
+    """``Scenario.engine`` picks the engine every evaluated case of
+    ``run()`` runs on, and no other engine runs."""
+    from repro.core import scan_engine
+
+    ran = []
+
+    def spy(name, mod):
+        real = getattr(mod, name)
+
+        def wrapped(*a, **kw):
+            ran.append(name)
+            return real(*a, **kw)
+
+        monkeypatch.setattr(mod, name, wrapped)
+
+    for name in ("_simulate_scalar", "_simulate_vector"):
+        spy(name, sim_mod)
+    spy("simulate_many_scan", scan_engine)
+    names = ("carbon-agnostic", "wait-awhile")
+    res = run(Scenario(**TINY, engine=engine), names)
+    assert all(len(res.weekly[n]) == 1 for n in names)
+    expect = {"scalar": "_simulate_scalar", "vector": "_simulate_vector",
+              "scan": "simulate_many_scan"}[engine]
+    assert ran and set(ran) == {expect}
+
+
 # --- Sweep -------------------------------------------------------------------
 
 

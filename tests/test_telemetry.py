@@ -18,7 +18,7 @@ import math
 
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core import CarbonDataOutage, baselines, simulate
 from repro.core.faults import (CorrelatedFaults, IidFaults, PreemptionFaults,
@@ -300,6 +300,10 @@ def _stub(policy, carbon, energy, mig=0.0, restore=None, serving=False):
        bm=st.floats(0.0, 1e6), rm=st.floats(0.0, 1e6),
        br=st.floats(0.0, 1e3), rr=st.floats(0.0, 1e3),
        serving=st.booleans())
+@example(bc=18390225.0, rc=0.0, be=1.0240131637839077e-295, re_=1e6,
+         bm=0.0, rm=0.0, br=0.0, rr=1e3, serving=False)
+@example(bc=1e9, rc=0.0, be=5e-324, re_=1e6, bm=0.0, rm=0.0, br=0.0,
+         rr=0.0, serving=True)
 def test_attribution_additivity_property(bc, rc, be, re_, bm, rm, br, rr,
                                          serving):
     """sum(causes) == delta_g, float-exact, for arbitrary finite
@@ -320,6 +324,20 @@ def test_attribution_additivity_property(bc, rc, be, re_, bm, rm, br, rr,
     assert att.causes[off_axis] == 0.0
     assert (att.causes[energy_axis] != 0.0) == (
         be != re_ and bc > 0 and be > 0)
+
+
+@pytest.mark.parametrize("be,re_,rr", [(1.0240131637839077e-295, 1e6, 1e3),
+                                        (5e-324, 1e6, 0.0),
+                                        (5e-324, 5e-324, 0.0)])
+def test_attribution_near_zero_baseline_energy(be, re_, rr):
+    """A baseline energy near zero prices the energy delta at an
+    astronomical CI; the causes stay finite and still sum exactly."""
+    res = _stub("p", 0.0, re_, restore=rr)
+    base = _stub("b", 18390225.0, be, restore=0.0)
+    att = attribute(res, base)
+    att.check()
+    assert all(math.isfinite(att.causes[c]) for c in CAUSES)
+    assert (att.causes["capacity_scaling"] != 0.0) == (be != re_)
 
 
 def test_attribution_fixed_twin():
